@@ -861,9 +861,22 @@ def _mh_worker(cfg: dict) -> int:
 
 def _mh_spawn(configs: list[dict], timeout: float = 600.0) -> list[dict]:
     """Run one worker subprocess per config (concurrently — they are the
-    ranks of one loopback job) and return their JSON results."""
+    ranks of one loopback job) and return their JSON results.
+
+    The ranks pin themselves to simulated CPU devices, so their numbers
+    are CPU numbers: on an accelerator host this refuses rather than
+    report them."""
     import socket
     import subprocess
+
+    import jax
+
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"--multihost runs its ranks on simulated CPU devices; this "
+            f"host's default backend is {jax.default_backend()!r}, whose "
+            f"chip one process holds at a time. Run it under "
+            f"JAX_PLATFORMS=cpu.")
 
     if len(configs) > 1:
         with socket.socket() as s:
@@ -1087,6 +1100,9 @@ def main(argv: list[str] | None = None) -> int:
             from repro.launch.mesh import force_host_device_count
 
             force_host_device_count(mesh, platform=None)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.pipelined:
         if args.smoke:
             args.envs_per_shard, args.steps, args.iters = 16, 16, 4

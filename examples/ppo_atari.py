@@ -28,6 +28,7 @@ import argparse
 import json
 
 import repro
+from repro.launch.compile_cache import enable_compile_cache
 from repro.rl.ppo import PPOConfig, train_device
 
 
@@ -50,6 +51,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out-json", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.tuned:
         num_envs, batch = 64, 64

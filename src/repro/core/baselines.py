@@ -136,7 +136,13 @@ class ForLoopEnv(_SyncSendRecv):
 
 
 def _subproc_worker(conn, shm_name, shape, dtype_str, lo, hi, factory_bytes):
-    """Worker process: owns envs [lo, hi); writes obs into shared memory."""
+    """Worker process: owns envs [lo, hi); writes obs into shared memory.
+
+    The worker is a host-CPU process: it restricts JAX to the CPU before
+    any backend starts, so it never tries to open the parent's chip."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
     factory = pickle.loads(factory_bytes)
     envs = [factory(i) for i in range(lo, hi)]
     shm = shared_memory.SharedMemory(name=shm_name)

@@ -67,7 +67,9 @@ class JittedHostEnv(HostEnv):
     """Wraps a pure-JAX Environment as a host env with a compiled step.
 
     The jitted call releases the GIL during XLA execution — the same
-    property that lets EnvPool's C++ envs scale across threads.
+    property that lets EnvPool's C++ envs scale across threads.  Every
+    call runs on the host CPU device: a host engine is the CPU baseline,
+    and on an accelerator host the chip belongs to the driving process.
     """
 
     def __init__(self, env, seed: int = 0, init_key=None):
@@ -75,6 +77,7 @@ class JittedHostEnv(HostEnv):
 
         self._env = env
         self.spec = env.spec
+        self._cpu = jax.devices("cpu")[0]
         self._jit_step = jax.jit(env.step)
         self._jit_init = jax.jit(env.init_state)
         self._seed = seed
@@ -87,6 +90,12 @@ class JittedHostEnv(HostEnv):
         self._state = None
 
     def reset(self) -> np.ndarray:
+        import jax
+
+        with jax.default_device(self._cpu):
+            return self._reset()
+
+    def _reset(self) -> np.ndarray:
         import jax
         import jax.numpy as jnp
 
@@ -105,7 +114,10 @@ class JittedHostEnv(HostEnv):
         return np.asarray(self._env.observe(self._state))
 
     def step(self, action):
-        self._state, ts = self._jit_step(self._state, action)
+        import jax
+
+        with jax.default_device(self._cpu):
+            self._state, ts = self._jit_step(self._state, action)
         return (
             np.asarray(ts.obs),
             float(ts.reward),

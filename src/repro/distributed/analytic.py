@@ -34,21 +34,12 @@ class CostBreakdown:
 
 
 def xla_cost_dict(compiled: Any) -> dict[str, float]:
-    """Normalized ``compiled.cost_analysis()`` across jaxlib versions.
-
-    Old jaxlib returns ``list[dict]`` (one entry per executable program),
-    new jaxlib returns a flat ``dict``; either may be ``None`` on backends
-    without the analysis.  Always returns a dict, empty when unavailable.
-    """
+    """``compiled.cost_analysis()`` as a dict, empty on backends without
+    the analysis."""
     try:
-        cost = compiled.cost_analysis()
+        return dict(compiled.cost_analysis() or {})
     except Exception:
         return {}
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
 
 
 def _attn_pairs(S_q: int, S_kv: int, window: int, causal: bool = True) -> float:

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -39,13 +40,13 @@ def _decode_kernel(
 
     s = q @ k.T                                          # (G, block_t)
     t_pos = ti * block_t + lax.broadcasted_iota(jnp.int32, (G, block_t), 1)
-    valid = t_pos < len_ref[0]
+    valid = t_pos < len_ref[b]
     s = jnp.where(valid, s, NEG_INF)
 
-    m = jnp.max(s, axis=-1)                              # (G,)
-    p = jnp.exp(s - m[:, None])
+    m = jnp.max(s, axis=-1, keepdims=True)               # (G, 1)
+    p = jnp.exp(s - m)
     p = jnp.where(valid, p, 0.0)
-    l = jnp.sum(p, axis=-1)
+    l = jnp.sum(p, axis=-1, keepdims=True)
     acc = p @ v                                          # (G, D)
 
     m_ref[0, 0, 0] = m
@@ -77,6 +78,9 @@ def decode_attention_fwd(
     lengths = lengths.astype(jnp.int32)
 
     kernel = functools.partial(_decode_kernel, block_t=block_t, sm_scale=scale)
+    # lengths sit whole in SMEM (read as a scalar per batch row); the
+    # per-chunk (max, sumexp) partials are (G, 1) columns, so every block's
+    # two minor dims are the array's own
     m, l, acc = pl.pallas_call(
         kernel,
         grid=grid,
@@ -84,20 +88,21 @@ def decode_attention_fwd(
             pl.BlockSpec((1, 1, G, D), lambda b, h, t: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_t, D), lambda b, h, t: (b, h, t, 0)),
             pl.BlockSpec((1, 1, block_t, D), lambda b, h, t: (b, h, t, 0)),
-            pl.BlockSpec((1,), lambda b, h, t: (b,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, t: (b, h, t, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, t: (b, h, t, 0)),
+            pl.BlockSpec((1, 1, 1, G, 1), lambda b, h, t: (b, h, t, 0, 0)),
+            pl.BlockSpec((1, 1, 1, G, 1), lambda b, h, t: (b, h, t, 0, 0)),
             pl.BlockSpec((1, 1, 1, G, D), lambda b, h, t: (b, h, t, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, n_chunks, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, n_chunks, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, n_chunks, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, n_chunks, G, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, Hkv, n_chunks, G, D), jnp.float32),
         ],
         interpret=interpret,
     )(qg, k, v, lengths)
+    m, l = m[..., 0], l[..., 0]
 
     # pass 2: combine partials (tiny; runs in XLA — or across shards as an
     # all-reduce when the cache is kv_seq-sharded)

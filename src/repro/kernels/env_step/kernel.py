@@ -11,14 +11,14 @@ the register level).
 
 Per-lane cost masking (``cost``): MuJoCo's solver cost is data-dependent
 (contacts add iterations), so a batch of envs needs lane ``n`` to run
-exactly ``cost[n]`` substeps.  The kernel unrolls ``n_sub = max_cost``
+exactly ``cost[n]`` substeps.  The kernel loops ``n_sub = max_cost``
 iterations and freezes finished lanes with selects — the same semantics
 JAX gives a vmapped per-lane ``while_loop``, so results are
 bitwise-identical to the per-lane engine path, but with one fused kernel
 launch per agent step instead of a lane-strided loop.
 
 Layout note: state is SoA (N, 28) with the 28 physics scalars in the minor
-(lane) dim; joints are 8-wide which packs two ants per 16-lane VPU subrow.
+(lane) dim; per-lane scalars (cost, reward) are (N, 1) columns.
 The physics op order matches ``MujocoLike.substep`` exactly (the contact
 model reads the PRE-update joint state) — see ref.py for the oracle.
 """
@@ -29,6 +29,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
 from repro.kernels.env_step.ref import _substep_core
@@ -45,13 +46,19 @@ def _env_kernel(state_ref, action_ref, out_ref, reward_ref, *, n_sub: int):
     ang = s[:, 9:12]
     q = s[:, 12:20]
     qd = s[:, 20:28]
-    reward = jnp.zeros((s.shape[0],), jnp.float32)
+    reward = jnp.zeros((s.shape[0], 1), jnp.float32)
 
-    for _ in range(n_sub):  # unrolled: n_sub is small and static
+    def body(_, carry):
+        pos, vel, rot, ang, q, qd, reward = carry
         pos, vel, rot, ang, q, qd, fwd, ctrl, alive = _substep_core(
             pos, vel, rot, ang, q, qd, a
         )
-        reward = ((reward + fwd) - ctrl) + alive
+        return pos, vel, rot, ang, q, qd, ((reward + fwd) - ctrl) + alive
+
+    # a rolled loop: unrolling n_sub substeps multiplies compile time
+    pos, vel, rot, ang, q, qd, reward = lax.fori_loop(
+        0, n_sub, body, (pos, vel, rot, ang, q, qd, reward)
+    )
 
     out_ref[...] = jnp.concatenate([pos, vel, rot, ang, q, qd], axis=-1).astype(
         out_ref.dtype
@@ -69,7 +76,7 @@ def _env_kernel_masked(state_ref, action_ref, cost_ref, reward_in_ref,
     class's float association exactly."""
     s = state_ref[...].astype(jnp.float32)        # (block_n, 28)
     a = jnp.clip(action_ref[...].astype(jnp.float32), -1.0, 1.0)
-    cost = cost_ref[...].astype(jnp.int32)        # (block_n,)
+    cost = cost_ref[...].astype(jnp.int32)        # (block_n, 1)
 
     pos = s[:, 0:3]
     vel = s[:, 3:6]
@@ -77,22 +84,19 @@ def _env_kernel_masked(state_ref, action_ref, cost_ref, reward_in_ref,
     ang = s[:, 9:12]
     q = s[:, 12:20]
     qd = s[:, 20:28]
-    reward = reward_in_ref[...].astype(jnp.float32)
+    reward = reward_in_ref[...].astype(jnp.float32)   # (block_n, 1)
 
-    for i in range(n_sub):  # unrolled: n_sub = spec.max_cost, small/static
-        n_pos, n_vel, n_rot, n_ang, n_q, n_qd, fwd, ctrl, alive = _substep_core(
-            pos, vel, rot, ang, q, qd, a
-        )
-        n_reward = ((reward + fwd) - ctrl) + alive
-        m = i < cost                              # (block_n,) lane mask
-        m2 = m[:, None]
-        pos = jnp.where(m2, n_pos, pos)
-        vel = jnp.where(m2, n_vel, vel)
-        rot = jnp.where(m2, n_rot, rot)
-        ang = jnp.where(m2, n_ang, ang)
-        q = jnp.where(m2, n_q, q)
-        qd = jnp.where(m2, n_qd, qd)
-        reward = jnp.where(m, n_reward, reward)
+    def body(i, carry):
+        pos, vel, rot, ang, q, qd, reward = carry
+        *new, fwd, ctrl, alive = _substep_core(pos, vel, rot, ang, q, qd, a)
+        new.append(((reward + fwd) - ctrl) + alive)
+        m = i < cost                              # (block_n, 1) lane mask
+        return tuple(jnp.where(m, n, o) for n, o in zip(new, carry))
+
+    # n_sub = spec.max_cost; a rolled loop keeps compile time flat in it
+    pos, vel, rot, ang, q, qd, reward = lax.fori_loop(
+        0, n_sub, body, (pos, vel, rot, ang, q, qd, reward)
+    )
 
     out_ref[...] = jnp.concatenate([pos, vel, rot, ang, q, qd], axis=-1).astype(
         out_ref.dtype
@@ -113,45 +117,40 @@ def env_substep_batch(
     """Fused batched substeps.  With ``cost=None`` every lane runs
     ``n_sub`` substeps; with a ``cost`` vector, lane ``n`` runs
     ``cost[n]`` (callers pass ``n_sub = spec.max_cost``) and the reward
-    output continues accumulating from ``reward0`` (default zeros)."""
+    output continues accumulating from ``reward0`` (default zeros).
+
+    A batch of at most ``block_n`` lanes is one block; a larger one that
+    ``block_n`` does not divide is padded up to a multiple of it (padded
+    lanes are computed and dropped), so any served block size runs."""
     N = state.shape[0]
     block_n = min(block_n, N)
-    if N % block_n:
-        raise ValueError(f"N={N} % block_n={block_n}")
-    out_specs = [
-        pl.BlockSpec((block_n, 28), lambda i: (i, 0)),
-        pl.BlockSpec((block_n,), lambda i: (i,)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((N, 28), state.dtype),
-        jax.ShapeDtypeStruct((N,), jnp.float32),
-    ]
+    n_pad = -N % block_n
+
+    def rows(width):
+        return pl.BlockSpec((block_n, width), lambda i: (i, 0))
+
+    args, in_specs = [state, action], [rows(28), rows(8)]
     if cost is None:
         kernel = functools.partial(_env_kernel, n_sub=n_sub)
-        return pl.pallas_call(
-            kernel,
-            grid=(N // block_n,),
-            in_specs=[
-                pl.BlockSpec((block_n, 28), lambda i: (i, 0)),
-                pl.BlockSpec((block_n, 8), lambda i: (i, 0)),
-            ],
-            out_specs=out_specs,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(state, action)
-    if reward0 is None:
-        reward0 = jnp.zeros((N,), jnp.float32)
-    kernel = functools.partial(_env_kernel_masked, n_sub=n_sub)
-    return pl.pallas_call(
+    else:
+        if reward0 is None:
+            reward0 = jnp.zeros((N,), jnp.float32)
+        kernel = functools.partial(_env_kernel_masked, n_sub=n_sub)
+        # per-lane scalars travel as (N, 1) columns: a rank-1 (block_n,)
+        # block must match the array's 128- or 1024-wide XLA tiling
+        args += [cost.astype(jnp.int32)[:, None],
+                 reward0.astype(jnp.float32)[:, None]]
+        in_specs += [rows(1), rows(1)]
+    Np = N + n_pad
+    out, reward = pl.pallas_call(
         kernel,
-        grid=(N // block_n,),
-        in_specs=[
-            pl.BlockSpec((block_n, 28), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, 8), lambda i: (i, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+        grid=(Np // block_n,),
+        in_specs=in_specs,
+        out_specs=[rows(28), rows(1)],
+        out_shape=[
+            jax.ShapeDtypeStruct((Np, 28), state.dtype),
+            jax.ShapeDtypeStruct((Np, 1), jnp.float32),
         ],
-        out_specs=out_specs,
-        out_shape=out_shape,
         interpret=interpret,
-    )(state, action, cost.astype(jnp.int32), reward0.astype(jnp.float32))
+    )(*[jnp.pad(x, ((0, n_pad), (0, 0))) for x in args])
+    return out[:N], reward[:N, 0]

@@ -35,6 +35,16 @@ def unpack_state(s):
     return s[..., 0:3], s[..., 3:6], s[..., 6:9], s[..., 9:12], s[..., 12:20], s[..., 20:28]
 
 
+def _legs(x, joint: int):
+    """``x[..., joint::2]`` (one joint of each of the 4 legs) built from
+    unit lane slices: Mosaic has no lowering for a strided lane slice
+    (it becomes a gather), and the values are the same either way."""
+    return jnp.concatenate(
+        [x[..., 2 * leg + joint:2 * leg + joint + 1] for leg in range(4)],
+        axis=-1,
+    )
+
+
 def _substep_core(pos, vel, rot, ang, q, qd, a):
     """One physics substep on unpacked (..., k) components.
 
@@ -42,29 +52,31 @@ def _substep_core(pos, vel, rot, ang, q, qd, a):
     reference, the fused multi-substep, and the Pallas kernel
     (kernel.py) all call this, so kernel-vs-oracle bitwise identity
     cannot drift through parallel edits.  Everything here must stay
-    Mosaic-lowerable (elementwise / concatenate / minor-axis reduce; no
-    scatter) and shape-polymorphic over (..., k).
+    Mosaic-lowerable (elementwise / concatenate / keepdims minor-axis
+    reduce; no scatter, no strided slice, no rank-1 intermediate) and
+    shape-polymorphic over (..., k).
 
     Mirrors MujocoLike.substep op-for-op (contact model reads the old
     state; reward term association matches ``reward_acc + fwd - ctrl +
     alive``).  Returns the new components plus this substep's reward
-    contribution terms (fwd, ctrl, alive) so callers can accumulate with
-    the exact association the env class uses.
+    contribution terms (fwd, ctrl as (..., 1); alive a scalar) so callers
+    can accumulate with the exact association the env class uses.
     """
     # contact model: PRE-update joint state (MujocoLike.substep order)
-    hip, knee = q[..., 0::2], q[..., 1::2]
+    hip, knee = _legs(q, 0), _legs(q, 1)
     foot_h = pos[..., 2:3] - (0.2 * jnp.cos(hip) + 0.2 * jnp.cos(hip + knee))
     contact = (foot_h < 0.05).astype(jnp.float32)
-    hip_vel = qd[..., 0::2]
-    thrust = jnp.sum(contact * (-hip_vel), axis=-1) * 0.08
-    normal = jnp.sum(contact * jnp.maximum(0.05 - foot_h, 0.0), axis=-1) * 120.0
+    hip_vel = _legs(qd, 0)
+    thrust = jnp.sum(contact * (-hip_vel), axis=-1, keepdims=True) * 0.08
+    normal = jnp.sum(contact * jnp.maximum(0.05 - foot_h, 0.0), axis=-1,
+                     keepdims=True) * 120.0
 
     # joint dynamics: torque − spring − damping
     qdd = 18.0 * a - 4.0 * q - 1.2 * qd
     qd = qd + DT * qdd
     q = jnp.clip(q + DT * qd, -1.2, 1.2)
 
-    acc = jnp.stack(
+    acc = jnp.concatenate(
         [thrust, jnp.zeros_like(thrust), -9.81 + normal], axis=-1
     )
     vel = (vel + DT * acc) * 0.995
@@ -73,14 +85,15 @@ def _substep_core(pos, vel, rot, ang, q, qd, a):
         [pos[..., :2], jnp.maximum(pos[..., 2:3], 0.1)], axis=-1
     )
 
-    asym = contact[..., 0] + contact[..., 1] - contact[..., 2] - contact[..., 3]
-    ang = (ang + DT * jnp.stack(
+    asym = (contact[..., 0:1] + contact[..., 1:2] - contact[..., 2:3]
+            - contact[..., 3:4])
+    ang = (ang + DT * jnp.concatenate(
         [0.4 * asym, 0.2 * asym, jnp.zeros_like(asym)], axis=-1
     )) * 0.98
     rot = rot + DT * ang
 
-    fwd = vel[..., 0] * DT * 20
-    ctrl = 0.5 * jnp.sum(a**2, axis=-1) * DT
+    fwd = vel[..., 0:1] * DT * 20
+    ctrl = 0.5 * jnp.sum(a**2, axis=-1, keepdims=True) * DT
     alive = 1.0 * DT
     return pos, vel, rot, ang, q, qd, fwd, ctrl, alive
 
@@ -94,7 +107,7 @@ def env_substep_reference(state: jnp.ndarray, action: jnp.ndarray
         pos, vel, rot, ang, q, qd, a
     )
     reward = fwd - ctrl + alive
-    return pack_state(pos, vel, rot, ang, q, qd), reward
+    return pack_state(pos, vel, rot, ang, q, qd), reward[..., 0]
 
 
 def env_multi_substep_reference(
@@ -129,12 +142,13 @@ def env_multi_substep_reference(
         )
         new_s = pack_state(pos, vel, rot, ang, q, qd)
         new_r = ((r + fwd) - ctrl) + alive
-        m = i < cost
-        s = jnp.where(m[:, None], new_s, s)
+        m = (i < cost)[:, None]
+        s = jnp.where(m, new_s, s)
         r = jnp.where(m, new_r, r)
         return i + 1, s, r
 
     _, state, reward = lax.while_loop(
-        cond, body, (jnp.int32(0), state, reward0.astype(jnp.float32))
+        cond, body,
+        (jnp.int32(0), state, reward0.astype(jnp.float32)[:, None]),
     )
-    return state, reward
+    return state, reward[:, 0]

@@ -60,9 +60,12 @@ def _grayscale_kernel(r_ref, g_ref, b_ref, o_ref):
     o_ref[...] = y.astype(o_ref.dtype)
 
 
-def grayscale_batch(rgb: jnp.ndarray, *, block_n: int = 8,
+def grayscale_batch(rgb: jnp.ndarray, *, block_n: int = 4,
                     interpret: bool = True) -> jnp.ndarray:
-    """(N, H, W, 3) uint8 -> (N, H, W) uint8 via the Pallas luma kernel."""
+    """(N, H, W, 3) uint8 -> (N, H, W) uint8 via the Pallas luma kernel.
+
+    Four int32 planes of ``block_n`` 210 x 160 screens, double-buffered,
+    must fit the 16 MiB of scoped VMEM: 8 needs 16.7 MiB, 4 fits."""
     n, h, w = rgb.shape[0], rgb.shape[1], rgb.shape[2]
     block_n = max(1, min(block_n, n))
     planes = [
@@ -152,12 +155,13 @@ def crop_batch(img: jnp.ndarray, top: int, left: int, height: int,
 # ---------------------------------------------------------------------- #
 def _render_kernel(bx_ref, by_ref, py_ref, ey_ref, r_ref, g_ref, b_ref):
     bn = r_ref.shape[0]
-    ys = lax.broadcasted_iota(jnp.float32, (bn, RGB_H, RGB_W), 1)
-    xs = lax.broadcasted_iota(jnp.float32, (bn, RGB_H, RGB_W), 2)
+    # Mosaic builds integer iotas only; the f32 grid is exact
+    ys = lax.broadcasted_iota(jnp.int32, (bn, RGB_H, RGB_W), 1
+                              ).astype(jnp.float32)
+    xs = lax.broadcasted_iota(jnp.int32, (bn, RGB_H, RGB_W), 2
+                              ).astype(jnp.float32)
     r, g, b = _pong_plane_values(
-        ys, xs,
-        bx_ref[...][:, None, None], by_ref[...][:, None, None],
-        py_ref[...][:, None, None], ey_ref[...][:, None, None],
+        ys, xs, bx_ref[...], by_ref[...], py_ref[...], ey_ref[...],
     )
     r_ref[...] = r.astype(r_ref.dtype)
     g_ref[...] = g.astype(g_ref.dtype)
@@ -172,12 +176,15 @@ def pong_render_batch(ball_x: jnp.ndarray, ball_y: jnp.ndarray,
     served block's screens in one fused render."""
     n = ball_x.shape[0]
     block_n = max(1, min(block_n, n))
+    # the per-lane scalars enter as (N, 1, 1): a block whose two minor
+    # dims are the array's own tiles for any block_n, where a rank-1
+    # (block_n,) block must be a multiple of 128
     ins = [
-        _pad_batch(jnp.asarray(v, jnp.float32), block_n)
+        _pad_batch(jnp.asarray(v, jnp.float32), block_n)[:, None, None]
         for v in (ball_x, ball_y, paddle_y, enemy_y)
     ]
     np_ = ins[0].shape[0]
-    sspec = pl.BlockSpec((block_n,), lambda i: (i,))
+    sspec = pl.BlockSpec((block_n, 1, 1), lambda i: (i, 0, 0))
     pspec = pl.BlockSpec((block_n, RGB_H, RGB_W), lambda i: (i, 0, 0))
     shape = jax.ShapeDtypeStruct((np_, RGB_H, RGB_W), jnp.int32)
     r, g, b = pl.pallas_call(

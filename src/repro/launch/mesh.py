@@ -125,12 +125,20 @@ def make_env_mesh(num_shards: int | None = None, axis_name: str = "env"):
     return _make(num_shards, axis_name)
 
 
-def make_production_mesh(*, multi_pod: bool = False):
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with ``Auto`` axes: the sharding rules place
+    arrays with ``with_sharding_constraint``, which refuses the
+    ``Explicit`` axes ``make_mesh`` defaults to."""
     import jax
+    from jax.sharding import AxisType
 
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
+def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(devices: int | None = None):
@@ -139,7 +147,7 @@ def make_debug_mesh(devices: int | None = None):
 
     n = devices or len(jax.devices())
     model = 2 if n % 2 == 0 and n > 1 else 1
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 # TPU v5e hardware model (roofline constants; harness spec)

@@ -247,38 +247,11 @@ def _record(history: list[dict], rec: dict, episodes: int, ep_sum: float,
 # --------------------------------------------------------------------- #
 # fully on-device driver
 # --------------------------------------------------------------------- #
-def train_device(
-    pool: "DeviceEnvPool | Any",   # any mesh engine (device/device-sharded)
-    cfg: PPOConfig,
-    seed: int = 0,
-    log_fn: Callable[[dict], None] | None = None,
-    hidden: tuple[int, ...] = (256, 128, 64),
-):
-    net = ActorCritic(pool.spec, hidden=hidden)
-    key = jax.random.PRNGKey(seed)
-    key, k_init, k_pool = jax.random.split(key, 3)
-    params = net.init(k_init)
-
-    # policy placement (distributed/sharding.py): replicated across the
-    # env mesh for small nets, sharded over it for large ones (Seed-RL
-    # style).  The placement commits the params, so the jitted
-    # train_step below inherits it without explicit in_shardings.
-    mesh = getattr(pool, "mesh", None)
-    if mesh is not None:
-        from repro.distributed.sharding import policy_shardings
-
-        placement = policy_shardings(
-            mesh, params, axis_name=getattr(pool, "axis_name", "env")
-        )
-        params = jax.tree.map(jax.device_put, params, placement)
-
-    M = pool.batch_size
-    steps_per_iter = cfg.num_steps * M
-    total_updates = max(
-        1, cfg.total_steps // steps_per_iter
-    ) * cfg.epochs * cfg.minibatches
-    opt, update = make_ppo_update(net, cfg, total_updates)
-    state = PPOState(params=params, opt=opt.init(params), step=jnp.int32(0))
+def make_train_step(pool, cfg: PPOConfig, net: ActorCritic, update):
+    """``train_device``'s program, jitted and not yet compiled:
+    ``train_step(state, ps, ts, kc, ku) -> (state, ps, ts, metrics)``.
+    Public so that a caller can lower or compile ahead of time the exact
+    program ``train_device`` runs, to read its memory and its kernels."""
 
     def train_step(state, ps, ts, kc, ku):
         """ONE fused collect+update: the rollout scan and the PPO epochs
@@ -316,7 +289,53 @@ def train_device(
         metrics = dict(metrics, episodes=episodes, ep_sum=ep_sum)
         return state, ps, ts, metrics
 
-    train_step = jax.jit(train_step, donate_argnums=(0, 1, 2))
+    return jax.jit(train_step, donate_argnums=(0, 1, 2))
+
+
+def train_device(
+    pool: "DeviceEnvPool | Any",   # any mesh engine (device/device-sharded)
+    cfg: PPOConfig,
+    seed: int = 0,
+    log_fn: Callable[[dict], None] | None = None,
+    hidden: tuple[int, ...] = (256, 128, 64),
+):
+    net = ActorCritic(pool.spec, hidden=hidden)
+    key = jax.random.PRNGKey(seed)
+    key, k_init, k_pool = jax.random.split(key, 3)
+    params = net.init(k_init)
+
+    M = pool.batch_size
+    steps_per_iter = cfg.num_steps * M
+    total_updates = max(
+        1, cfg.total_steps // steps_per_iter
+    ) * cfg.epochs * cfg.minibatches
+    opt, update = make_ppo_update(net, cfg, total_updates)
+    state = PPOState(params=params, opt=opt.init(params), step=jnp.int32(0))
+
+    # policy placement (distributed/sharding.py): replicated across the
+    # env mesh for small nets, sharded over it for large ones (Seed-RL
+    # style); the optimizer moments follow their params.  The whole
+    # learner state is committed, so the jitted train_step below
+    # inherits it without explicit in_shardings — and takes back on the
+    # next call the placement it returned: a leaf left unplaced would
+    # come back placed and compile the program a second time.
+    mesh = getattr(pool, "mesh", None)
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from repro.distributed.sharding import policy_shardings
+
+        placement = policy_shardings(
+            mesh, params, axis_name=getattr(pool, "axis_name", "env")
+        )
+        rep = NamedSharding(mesh, PartitionSpec())
+        state = jax.device_put(state, PPOState(
+            params=placement,
+            opt=state.opt.replace(mu=placement, nu=placement, count=rep),
+            step=rep,
+        ))
+
+    train_step = make_train_step(pool, cfg, net, update)
 
     ps, ts = pool.reset(k_pool)
     if hasattr(pool, "device_put"):

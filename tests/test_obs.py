@@ -323,10 +323,13 @@ def test_span_fence_covers_async_dispatch():
 
 def test_tracer_threaded_buffers_and_dump(tmp_path):
     tr = Tracer()
+    # all four threads live at once: an exited thread's id can be reused
+    together = threading.Barrier(4)
 
     def worker():
         with tr.span("w"):
             time.sleep(0.005)
+            together.wait(timeout=10)
 
     threads = [threading.Thread(target=worker) for _ in range(4)]
     for t in threads:

@@ -24,7 +24,9 @@ from repro.distributed.sharding import (
 def mesh():
     # 1-device "mesh" with the production axis names: divisibility logic
     # still exercised (extent 1 divides everything)
-    return jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_debug_mesh
+
+    return make_debug_mesh(1)
 
 
 def test_resolve_basic(mesh):
