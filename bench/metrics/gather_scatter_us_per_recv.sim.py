@@ -1,0 +1,13 @@
+"""Device time per recv of the engine's gathers and scatters of lane
+rows (``pool.send``, ``pool.gather``, ``pool.writeback``), per chip."""
+
+from bench.lib import phases
+
+SCOPES = ("pool.send", "pool.gather", "pool.writeback")
+
+
+def read(trace, counts):
+    ph = phases.of(trace)
+    if not counts.get("recvs") or ph is None or not ph.has_scopes():
+        return None
+    return 1e6 * ph.scope_s(*SCOPES) / len(trace.ops) / counts["recvs"]

@@ -83,6 +83,15 @@ from repro.obs.telemetry import (
     telemetry_local,
     telemetry_shard,
 )
+from repro.obs.trace import (
+    ENV_STEP,
+    GATHER,
+    SEND,
+    TELEMETRY,
+    TICK,
+    TRANSFORM,
+    WRITEBACK,
+)
 from repro.utils.pytree import pytree_dataclass, tree_gather
 
 ENV_AXIS = "env"
@@ -373,6 +382,7 @@ class MeshEnvPool:
             phase=ps.phase, cost=ps.cost, send_tick=ps.send_tick, tick=ps.tick
         )
 
+    @jax.named_scope(SEND)
     def _local_send(self, ps: PoolState, actions: jnp.ndarray,
                     local_ids: jnp.ndarray) -> PoolState:
         sel_states = tree_gather(ps.env_states, local_ids)
@@ -407,7 +417,8 @@ class MeshEnvPool:
         Batches must stay in the recv grouping (shard-major)."""
         if _traced(ps, actions, env_ids):
             return self._send_impl(ps, actions, env_ids)
-        return self._jit_send(ps, actions, env_ids)
+        with jax.profiler.TraceAnnotation("pool.send"):
+            return self._jit_send(ps, actions, env_ids)
 
     # ------------------------------------------------------------------ #
     # recv — StateBufferQueue block of M results (per-shard top-M/D)
@@ -422,12 +433,11 @@ class MeshEnvPool:
         scattered back onto ``PoolState``."""
         if not self.pipeline:
             return ps, out
-        blk = self.pipeline.gather(ps.tf_state, idx)
-        blk, out = self.pipeline.apply(blk, out)
-        return (
-            ps.replace(tf_state=self.pipeline.scatter(ps.tf_state, idx, blk)),
-            out,
-        )
+        with jax.named_scope(TRANSFORM):
+            blk = self.pipeline.gather(ps.tf_state, idx)
+            blk, out = self.pipeline.apply(blk, out)
+            tf_state = self.pipeline.scatter(ps.tf_state, idx, blk)
+        return ps.replace(tf_state=tf_state), out
 
     def _recv_topm(self, ps: PoolState) -> tuple[PoolState, TimeStep]:
         full_block = self._m_local == ps.phase.shape[0]
@@ -439,59 +449,70 @@ class MeshEnvPool:
             # read BEFORE ``complete`` advances the tick.  A full-size
             # block serves every lane, so wait stays in lane order and
             # record_serve takes its scatter-free fast path.
-            wait = (ps.tick - ps.send_tick if full_block
-                    else ps.tick - ps.send_tick[idx])
+            with jax.named_scope(TELEMETRY):
+                wait = (ps.tick - ps.send_tick if full_block
+                        else ps.tick - ps.send_tick[idx])
         else:
             idx = self.scheduler.select(self._sched_view(ps), self._m_local)
 
-        sel_states = tree_gather(ps.env_states, idx)
-        sel_actions = ps.actions[idx]
-        sel_phase = ps.phase[idx]
-        need_step = sel_phase == HAS_ACTION
+        with jax.named_scope(GATHER):
+            sel_states = tree_gather(ps.env_states, idx)
+            sel_actions = ps.actions[idx]
+            sel_phase = ps.phase[idx]
+            need_step = sel_phase == HAS_ACTION
 
-        # batched-native step: ONE fused multi-substep call for the
-        # whole block (per-lane data-dependent cost handled inside)
-        new_states, ts = self.benv.v_step(sel_states, sel_actions, need_step)
+        with jax.named_scope(ENV_STEP):
+            # batched-native step: ONE fused multi-substep call for the
+            # whole block (per-lane data-dependent cost handled inside)
+            new_states, ts = self.benv.v_step(sel_states, sel_actions,
+                                              need_step)
+            # ONE observe pass over the post-step states serves every
+            # lane: for stepped lanes ``new_states`` is the finalized
+            # state (its observe is bitwise ``ts.obs``); for ``do=False``
+            # lanes ``v_step`` restored the original state, so this
+            # re-derives the CURRENT obs — the phantom-obs fix.  Not
+            # reading ``ts.obs`` lets XLA dead-code-eliminate the
+            # finalize observe (one frame render per recv for
+            # render-on-observe envs like AtariLike).
+            obs = self.benv.v_observe(new_states)
 
-        # ONE observe pass over the post-step states serves every lane:
-        # for stepped lanes ``new_states`` is the finalized state (its
-        # observe is bitwise ``ts.obs``); for ``do=False`` lanes
-        # ``v_step`` restored the original state, so this re-derives the
-        # CURRENT obs — the phantom-obs fix.  Not reading ``ts.obs``
-        # lets XLA dead-code-eliminate the finalize observe (one frame
-        # render per recv for render-on-observe envs like AtariLike).
-        obs = self.benv.v_observe(new_states)
-        out = TimeStep(
-            obs=obs,
-            reward=jnp.where(need_step, ts.reward, ps.r_reward[idx]),
-            done=jnp.where(need_step, ts.done, ps.r_done[idx]),
-            terminated=jnp.where(need_step, ts.terminated, ps.r_term[idx]),
-            truncated=jnp.where(need_step, ts.truncated, ps.r_trunc[idx]),
-            env_id=idx,
-            episode_return=jnp.where(
-                need_step, ts.episode_return, ps.r_ep_return[idx]
-            ),
-            episode_length=jnp.where(
-                need_step, ts.episode_length, ps.r_ep_length[idx]
-            ),
-            step_cost=jnp.where(need_step, ts.step_cost, ps.r_cost[idx]),
-        )
-        env_states = jax.tree.map(
-            lambda full, upd: full.at[idx].set(upd), ps.env_states, new_states
-        )
-        ss = self.scheduler.complete(self._sched_view(ps), idx)
-        ps = ps.replace(
-            env_states=env_states,
-            phase=ss.phase,
-            r_reward=ps.r_reward.at[idx].set(out.reward),
-            r_done=ps.r_done.at[idx].set(out.done),
-            r_term=ps.r_term.at[idx].set(out.terminated),
-            r_trunc=ps.r_trunc.at[idx].set(out.truncated),
-            r_ep_return=ps.r_ep_return.at[idx].set(out.episode_return),
-            r_ep_length=ps.r_ep_length.at[idx].set(out.episode_length),
-            r_cost=ps.r_cost.at[idx].set(out.step_cost),
-            tick=ss.tick,
-        )
+        with jax.named_scope(GATHER):
+            out = TimeStep(
+                obs=obs,
+                reward=jnp.where(need_step, ts.reward, ps.r_reward[idx]),
+                done=jnp.where(need_step, ts.done, ps.r_done[idx]),
+                terminated=jnp.where(need_step, ts.terminated,
+                                     ps.r_term[idx]),
+                truncated=jnp.where(need_step, ts.truncated,
+                                    ps.r_trunc[idx]),
+                env_id=idx,
+                episode_return=jnp.where(
+                    need_step, ts.episode_return, ps.r_ep_return[idx]
+                ),
+                episode_length=jnp.where(
+                    need_step, ts.episode_length, ps.r_ep_length[idx]
+                ),
+                step_cost=jnp.where(need_step, ts.step_cost,
+                                    ps.r_cost[idx]),
+            )
+        with jax.named_scope(WRITEBACK):
+            env_states = jax.tree.map(
+                lambda full, upd: full.at[idx].set(upd), ps.env_states,
+                new_states
+            )
+            ss = self.scheduler.complete(self._sched_view(ps), idx)
+            ps = ps.replace(
+                env_states=env_states,
+                phase=ss.phase,
+                r_reward=ps.r_reward.at[idx].set(out.reward),
+                r_done=ps.r_done.at[idx].set(out.done),
+                r_term=ps.r_term.at[idx].set(out.terminated),
+                r_trunc=ps.r_trunc.at[idx].set(out.truncated),
+                r_ep_return=ps.r_ep_return.at[idx].set(out.episode_return),
+                r_ep_length=ps.r_ep_length.at[idx].set(out.episode_length),
+                r_cost=ps.r_cost.at[idx].set(out.step_cost),
+                tick=ss.tick,
+            )
         if self.obs:
             ps = ps.replace(
                 telemetry=record_serve(
@@ -507,6 +528,7 @@ class MeshEnvPool:
     # ------------------------------------------------------------------ #
     # masked (event-driven tick) mode — the literal-semantics ablation
     # ------------------------------------------------------------------ #
+    @jax.named_scope(TICK)
     def _tick(self, ps: PoolState) -> PoolState:
         """Advance every HAS_ACTION lane one substep (idle lanes masked)."""
         busy = ps.phase == HAS_ACTION
@@ -567,28 +589,34 @@ class MeshEnvPool:
         def not_enough(s: PoolState):
             return jnp.sum(s.phase == READY) < m
 
-        ps = lax.while_loop(not_enough, self._tick, ps)
+        with jax.named_scope(TICK):
+            ps = lax.while_loop(not_enough, self._tick, ps)
         # completion order ≈ send_tick order among READY (policy-
         # independent by the select_ready contract)
         idx = self.scheduler.select_ready(self._sched_view(ps), m)
-        sel_states = tree_gather(ps.env_states, idx)
-        out = TimeStep(
-            obs=self.benv.v_observe(sel_states),
-            reward=ps.r_reward[idx],
-            done=ps.r_done[idx],
-            terminated=ps.r_term[idx],
-            truncated=ps.r_trunc[idx],
-            env_id=idx,
-            episode_return=ps.r_ep_return[idx],
-            episode_length=ps.r_ep_length[idx],
-            step_cost=ps.r_cost[idx],
-        )
+        with jax.named_scope(GATHER):
+            sel_states = tree_gather(ps.env_states, idx)
+        with jax.named_scope(ENV_STEP):
+            obs = self.benv.v_observe(sel_states)
+        with jax.named_scope(GATHER):
+            out = TimeStep(
+                obs=obs,
+                reward=ps.r_reward[idx],
+                done=ps.r_done[idx],
+                terminated=ps.r_term[idx],
+                truncated=ps.r_trunc[idx],
+                env_id=idx,
+                episode_return=ps.r_ep_return[idx],
+                episode_length=ps.r_ep_length[idx],
+                step_cost=ps.r_cost[idx],
+            )
         ss = self.scheduler.complete(self._sched_view(ps), idx)
         if self.obs:
             # wait since the step COMPLETED (``_tick`` stamps send_tick
             # at finish); substeps were already counted per-tick, so
             # the serve records with stepped_mask=False
-            wait = ps.tick - ps.send_tick[idx]
+            with jax.named_scope(TELEMETRY):
+                wait = ps.tick - ps.send_tick[idx]
         ps = ps.replace(phase=ss.phase, tick=ss.tick)
         if self.obs:
             ps = ps.replace(
@@ -623,8 +651,9 @@ class MeshEnvPool:
                 # golden pins it (variable cost, not env-id-sorted), so
                 # D=1 vs D>1 sync ordering coincides only for
                 # fixed-cost tasks; see the module docstring.
-                order = jnp.argsort(ts.env_id)
-                ts = jax.tree.map(lambda x: x[order], ts)
+                with jax.named_scope(WRITEBACK):
+                    order = jnp.argsort(ts.env_id)
+                    ts = jax.tree.map(lambda x: x[order], ts)
             return self._shard_view(local), ts
 
         return self._smap(recv_shard, 1, n_out=2)(ps)
@@ -632,7 +661,8 @@ class MeshEnvPool:
     def recv(self, ps: PoolState) -> tuple[PoolState, TimeStep]:
         if _traced(ps):
             return self._recv_impl(ps)
-        return self._jit_recv(ps)
+        with jax.profiler.TraceAnnotation("pool.recv"):
+            return self._jit_recv(ps)
 
     # ------------------------------------------------------------------ #
     # gym-style combined step + reset views
@@ -642,7 +672,8 @@ class MeshEnvPool:
         """``step = send ∘ recv`` (paper §3.1)."""
         if _traced(ps, actions, env_ids):
             return self._recv_impl(self._send_impl(ps, actions, env_ids))
-        return self._jit_step(ps, actions, env_ids)
+        with jax.profiler.TraceAnnotation("pool.step"):
+            return self._jit_step(ps, actions, env_ids)
 
     # ------------------------------------------------------------------ #
     # cached jitted entry points for eager callers (see ``_traced``)
@@ -671,7 +702,8 @@ class MeshEnvPool:
 
     def reset(self, key: jax.Array) -> tuple[PoolState, TimeStep]:
         """Sync-style reset: init + drain the first batch of M results."""
-        return self._jit_reset(key)
+        with jax.profiler.TraceAnnotation("pool.reset"):
+            return self._jit_reset(key)
 
     # ------------------------------------------------------------------ #
     # telemetry snapshot (core/protocol.py ``stats()`` contract)
@@ -685,15 +717,17 @@ class MeshEnvPool:
             raise RuntimeError(
                 "telemetry disabled: pool was constructed with obs=False"
             )
-        tel, tick = ps.telemetry, ps.tick
-        if self.is_multiprocess:
-            # multi-host: counter leaves live on remote shards, so gather
-            # a replicated copy first.  Fixed-size integer leaves on an
-            # explicit stats() call only — never the hot path — and the
-            # cross-shard sums stay integer adds, so the snapshot remains
-            # bitwise identical to the single-process one.
-            tel, tick = self.replicate((tel, tick))
-        return snapshot_device(tel, tick)
+        with jax.profiler.TraceAnnotation("pool.stats"):
+            tel, tick = ps.telemetry, ps.tick
+            if self.is_multiprocess:
+                # multi-host: counter leaves live on remote shards, so
+                # gather a replicated copy first.  Fixed-size integer
+                # leaves on an explicit stats() call only — never the
+                # hot path — and the cross-shard sums stay integer adds,
+                # so the snapshot remains bitwise identical to the
+                # single-process one.
+                tel, tick = self.replicate((tel, tick))
+            return snapshot_device(tel, tick)
 
     # ------------------------------------------------------------------ #
     # paper Appendix E: jittable handle API

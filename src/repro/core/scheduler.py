@@ -80,6 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.obs.trace import SELECT
 from repro.utils.pytree import pytree_dataclass
 
 # lane phases (shared with the device pool; duplicated values would skew)
@@ -138,6 +139,7 @@ class Scheduler:
             tick=jnp.int32(0),
         )
 
+    @jax.named_scope(SELECT)
     def enqueue(self, ss: SchedState, lane_ids: jnp.ndarray,
                 costs: jnp.ndarray) -> SchedState:
         """Lanes ``lane_ids`` received an action with predicted ``costs``."""
@@ -148,6 +150,7 @@ class Scheduler:
             send_tick=ss.send_tick.at[lane_ids].set(ss.tick),
         )
 
+    @jax.named_scope(SELECT)
     def select(self, ss: SchedState, m: int) -> jnp.ndarray:
         """The ``m`` lanes to serve this recv (lowest priority value
         first).  Never returns a WAITING lane while ≥ m serviceable
@@ -156,6 +159,7 @@ class Scheduler:
         _, idx = lax.top_k(-self.priority(ss), m)
         return idx.astype(jnp.int32)
 
+    @jax.named_scope(SELECT)
     def select_info(self, ss: SchedState, m: int
                     ) -> tuple[jnp.ndarray, jnp.ndarray]:
         """``(idx, overdue_admits)`` — the selection plus the scalar
@@ -166,6 +170,7 @@ class Scheduler:
         unconditionally without perturbing fifo/sjf selections."""
         return self.select(ss, m), jnp.int32(0)
 
+    @jax.named_scope(SELECT)
     def select_ready(self, ss: SchedState, m: int) -> jnp.ndarray:
         """Completion-order pick among READY lanes only — the masked
         (event-driven tick) engine's recv, where results materialize by
@@ -178,6 +183,7 @@ class Scheduler:
         _, idx = lax.top_k(-prio, m)
         return idx.astype(jnp.int32)
 
+    @jax.named_scope(SELECT)
     def complete(self, ss: SchedState, idx: jnp.ndarray) -> SchedState:
         """Served lanes go back to WAITING; the global tick advances."""
         return ss.replace(
@@ -281,6 +287,7 @@ class HierarchicalScheduler(Scheduler):
     def select(self, ss: SchedState, m: int) -> jnp.ndarray:
         return self.select_info(ss, m)[0]
 
+    @jax.named_scope(SELECT)
     def select_info(self, ss: SchedState, m: int
                     ) -> tuple[jnp.ndarray, jnp.ndarray]:
         tau = self._tau(ss, m)

@@ -43,6 +43,7 @@ from jax import lax
 from repro.core.device_pool import DeviceEnvPool, PoolState
 from repro.core.protocol import EnvPool, is_functional, to_timestep
 from repro.core.specs import TimeStep
+from repro.obs.trace import COLLECT, POLICY
 from repro.utils.pytree import tree_stack
 
 # any object satisfying core.protocol.EnvPool (kept loose for typing)
@@ -80,10 +81,12 @@ def build_collect_fn(
     if is_functional(pool):
         def one_step(carry, key):
             ps, ts, params = carry
-            actions = policy_fn(params, ts.obs, key)
+            with jax.named_scope(POLICY):
+                actions = policy_fn(params, ts.obs, key)
             ps, new_ts = pool.step(ps, actions, ts.env_id)
             return (ps, new_ts, params), (ts, actions)
 
+        @jax.named_scope(COLLECT)
         def collect(ps: PoolState, params: Any, last_ts: TimeStep,
                     key: jax.Array):
             keys = jax.random.split(key, num_steps)

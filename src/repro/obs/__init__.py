@@ -8,9 +8,10 @@ Three pieces, consumed by every engine row of the matrix:
   * ``obs.metrics``   — the unified registry (counters / gauges /
     fixed-bucket histograms, labeled series, JSON export) every
     reporting surface publishes through;
-  * ``obs.trace``     — fenced Chrome-trace/Perfetto spans: the
-    ``block_until_ready`` bucket discipline as a reusable context
-    manager, with per-thread buffers and a ``trace.json`` dump.
+  * ``obs.trace``     — fenced host spans: the ``block_until_ready``
+    bucket discipline as a reusable context manager, each span also a
+    ``jax.profiler`` annotation; and the ``jax.named_scope`` names of
+    the pool's phases inside the device program.
 """
 
 from repro.obs.metrics import (

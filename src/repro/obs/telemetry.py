@@ -54,8 +54,10 @@ from __future__ import annotations
 
 from typing import Any
 
+import jax
 import numpy as np
 
+from repro.obs.trace import TELEMETRY
 from repro.utils.pytree import pytree_dataclass
 
 # fixed histogram edges (recv ticks waited): bucket b counts waits in
@@ -141,6 +143,7 @@ def _lane_counts(idx, wait, num_envs):
     )
 
 
+@jax.named_scope(TELEMETRY)
 def record_serve(
     tele: Telemetry,
     idx,            # (M,) served lane indices
@@ -183,6 +186,7 @@ def record_serve(
     )
 
 
+@jax.named_scope(TELEMETRY)
 def record_finished(tele: Telemetry, finished, cost) -> Telemetry:
     """Masked-mode substep accounting: lanes whose step completed this
     tick (``_tick`` body).  The serve itself is recorded later by

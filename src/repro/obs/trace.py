@@ -1,4 +1,5 @@
-"""Fenced trace spans — a Chrome-trace-event (Perfetto) emitter.
+"""Fenced host spans on the profiler's clock, and the names of the
+pool's phases inside the device program.
 
 JAX dispatch is asynchronous: ``time.time()`` around a jitted call
 measures dispatch, and the compute silently leaks into whichever span
@@ -15,33 +16,59 @@ reusable implementation:
         out = pool.step(a, ids)     # host-blocking: no fence needed
 
     tr.totals()                     # {"inference": 1.2, ...} seconds
-    tr.dump("trace.json")           # open in chrome://tracing / Perfetto
 
-Spans nest (they are plain context managers); every span records one
-complete ("ph": "X") Chrome trace event with microsecond timestamps.
-Buffers are per-thread (a ``threading.local`` list registered under the
-thread id), so the thread/subprocess engines' worker threads can trace
-without locking each other on the hot path — the merge happens at
-``dump()``/``events()`` time.  ``totals()`` aggregates wall seconds per
-span name across all threads: exactly the paper's Fig-4 buckets when
-the spans are named ``env_step``/``inference``/``train``/``other``.
+Spans nest (they are plain context managers).  ``totals()`` aggregates
+wall seconds per span name across all threads: exactly the paper's
+Fig-4 buckets when the spans are named ``env_step``/``inference``/
+``train``/``other``.  Each span also opens a
+``jax.profiler.TraceAnnotation`` of its name for its whole duration,
+fence included, so a running ``jax.profiler`` session records it in the
+same ``.xplane.pb`` as the device's operations, on one clock.  With no
+session running the annotation records nothing.
+
+The device side: the pool's phases run inside one XLA program, so a
+host span cannot split them.  Each phase is wrapped in
+``jax.named_scope`` with one of the names below; the scope reaches the
+compiled HLO as the ``op_name`` metadata of every operation it emits
+(``jit(collect)/collect/while/body/pool.gather/gather``) and from there
+the profiler's ``tf_op`` stat.  Scopes are metadata only: they change
+no operation, fusion or number.  A profile reader assigns each device
+operation to the innermost of these names on its path; a fusion carries
+its root instruction's path, so it counts under its root's phase.
 """
 
 from __future__ import annotations
 
-import json
-import os
 import threading
 import time
 from typing import Any, Callable
 
+import jax
+
+# device phases of the pool (core/engine.py, core/scheduler.py,
+# obs/telemetry.py) and of the collect loop (core/xla_loop.py)
+SEND = "pool.send"              # action and progress scatters, step cost
+SELECT = "pool.select"          # the scheduler: select, enqueue, complete
+GATHER = "pool.gather"          # served lanes' state, action, result rows
+ENV_STEP = "pool.env_step"      # the batched env's step and observe
+WRITEBACK = "pool.writeback"    # scatter of the stepped states and results
+TICK = "pool.tick"              # the masked engine's substep loop
+TELEMETRY = "pool.telemetry"    # in-graph counters
+TRANSFORM = "pool.transform"    # the transform pipeline at serve time
+COLLECT = "collect"             # the collect scan, outside the pool
+POLICY = "collect.policy"       # the policy inside the collect scan
+# the fused PPO step (rl/ppo.py::make_train_step)
+PPO_COLLECT = "ppo.collect"
+PPO_GAE = "ppo.gae"
+PPO_UPDATE = "ppo.update"
+
+SCOPES = (SEND, SELECT, GATHER, ENV_STEP, WRITEBACK, TICK, TELEMETRY,
+          TRANSFORM, COLLECT, POLICY, PPO_COLLECT, PPO_GAE, PPO_UPDATE)
+
 
 def _fence(payload: Any) -> None:
-    """Block until every array in ``payload`` is computed.  Lazy jax
-    import so a pure-host tracer user never pays for it; non-jax
+    """Block until every array in ``payload`` is computed; non-jax
     payloads (numpy, python) pass through jax's own no-op handling."""
-    import jax
-
     jax.block_until_ready(payload)
 
 
@@ -60,110 +87,59 @@ class Span:
 
 
 class _SpanCtx:
-    __slots__ = ("_tracer", "_name", "_cat", "_payload", "_span", "_t0")
+    __slots__ = ("_tracer", "_name", "_payload", "_span", "_t0", "_note")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 fence: Any) -> None:
+    def __init__(self, tracer: "Tracer", name: str, fence: Any) -> None:
         self._tracer = tracer
         self._name = name
-        self._cat = cat
         self._payload = fence
         self._span: Span | None = None
         self._t0 = 0.0
+        self._note = None
 
     def __enter__(self) -> Span:
         self._span = Span()
+        self._note = jax.profiler.TraceAnnotation(self._name)
+        self._note.__enter__()
         self._t0 = self._tracer._clock()
         return self._span
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        payload = self._span._payload
-        if payload is None:
-            payload = self._payload
-        if payload is not None and exc_type is None:
-            _fence(payload)
-        self._tracer._close(self._name, self._cat, self._t0,
-                            self._tracer._clock())
+        try:
+            payload = self._span._payload
+            if payload is None:
+                payload = self._payload
+            if payload is not None and exc_type is None:
+                _fence(payload)
+            self._tracer._close(self._name, self._t0, self._tracer._clock())
+        finally:
+            self._note.__exit__(exc_type, exc, tb)
 
 
 class Tracer:
-    """Per-thread span buffers + one merged Chrome-trace export."""
+    """Fenced spans: wall seconds per span name, summed over threads."""
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter):
         self._clock = clock
-        self._epoch = clock()
         self._lock = threading.Lock()
-        self._local = threading.local()
-        # tid -> event list; threads only ever append to their own list
-        self._buffers: dict[int, list[tuple]] = {}
         self._totals: dict[str, float] = {}
 
-    # ------------------------------------------------------------------ #
-    def span(self, name: str, cat: str = "engine",
-             fence: Any = None) -> _SpanCtx:
+    def span(self, name: str, fence: Any = None) -> _SpanCtx:
         """Context manager for one fenced span.  ``fence`` (or a later
         ``sp.fence(...)`` call on the yielded handle) supplies the
         outputs to ``block_until_ready`` before the span closes; omit
         it for host-blocking work."""
-        return _SpanCtx(self, name, cat, fence)
+        return _SpanCtx(self, name, fence)
 
-    def _buf(self) -> list[tuple]:
-        buf = getattr(self._local, "buf", None)
-        if buf is None:
-            buf = self._local.buf = []
-            with self._lock:
-                self._buffers[threading.get_ident()] = buf
-        return buf
-
-    def _close(self, name: str, cat: str, t0: float, t1: float) -> None:
-        self._buf().append((name, cat, t0, t1, threading.get_ident()))
+    def _close(self, name: str, t0: float, t1: float) -> None:
         with self._lock:
             self._totals[name] = self._totals.get(name, 0.0) + (t1 - t0)
 
-    def instant(self, name: str, cat: str = "engine") -> None:
-        """Zero-duration marker event."""
-        t = self._clock()
-        self._buf().append((name, cat, t, t, threading.get_ident()))
-
-    # ------------------------------------------------------------------ #
     def totals(self) -> dict[str, float]:
         """Aggregate wall seconds per span name (all threads) — the
         Fig-4 profile buckets."""
         with self._lock:
             return dict(self._totals)
 
-    def events(self) -> list[dict]:
-        """All spans as Chrome trace events (complete "X" events,
-        microsecond timestamps relative to tracer creation)."""
-        with self._lock:
-            buffers = list(self._buffers.items())
-        pid = os.getpid()
-        out = []
-        for tid, buf in buffers:
-            for name, cat, t0, t1, _ in list(buf):
-                out.append({
-                    "name": name,
-                    "cat": cat,
-                    "ph": "X",
-                    "ts": (t0 - self._epoch) * 1e6,
-                    "dur": (t1 - t0) * 1e6,
-                    "pid": pid,
-                    "tid": tid,
-                })
-        out.sort(key=lambda e: e["ts"])
-        return out
 
-    def dump(self, path: str = "trace.json") -> str:
-        """Write the Chrome trace JSON (open in chrome://tracing or
-        https://ui.perfetto.dev)."""
-        payload = {
-            "traceEvents": self.events(),
-            "displayTimeUnit": "ms",
-        }
-        with open(path, "w") as f:
-            json.dump(payload, f)
-            f.write("\n")
-        return path
-
-
-__all__ = ["Span", "Tracer"]
+__all__ = ["SCOPES", "Span", "Tracer"]

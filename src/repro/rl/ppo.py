@@ -72,7 +72,7 @@ import numpy as np
 from repro.core.device_pool import DeviceEnvPool
 from repro.core.protocol import EnvPool, is_functional
 from repro.obs.metrics import MetricsRegistry, publish_history
-from repro.obs.trace import Tracer
+from repro.obs.trace import PPO_COLLECT, PPO_GAE, PPO_UPDATE, Tracer
 from repro.rl.gae import gae
 from repro.rl.nets import ActorCritic
 from repro.rl.vtrace import vtrace
@@ -270,17 +270,20 @@ def make_train_step(pool, cfg: PPOConfig, net: ActorCritic, update):
             }
             return (ps, new_ts), data
 
-        keys = jax.random.split(kc, cfg.num_steps)
-        (ps, ts), traj = jax.lax.scan(one_step, (ps, ts), keys)
-        _, last_v = net.forward(state.params, ts.obs)
-        adv, ret = gae(traj["rewards"], traj["values"], traj["dones"],
-                       last_v, cfg.gamma, cfg.lam)
+        with jax.named_scope(PPO_COLLECT):
+            keys = jax.random.split(kc, cfg.num_steps)
+            (ps, ts), traj = jax.lax.scan(one_step, (ps, ts), keys)
+        with jax.named_scope(PPO_GAE):
+            _, last_v = net.forward(state.params, ts.obs)
+            adv, ret = gae(traj["rewards"], traj["values"], traj["dones"],
+                           last_v, cfg.gamma, cfg.lam)
         rollout = {
             "obs": traj["obs"], "actions": traj["actions"],
             "logp": traj["logp"], "values": traj["values"],
             "adv": adv, "ret": ret,
         }
-        state, metrics = update(state, rollout, ku)
+        with jax.named_scope(PPO_UPDATE):
+            state, metrics = update(state, rollout, ku)
         # episode stats reduced in-graph: only scalars cross to the host.
         # The count and sum cross separately — the mean is formed host-
         # side (``_record``) so a zero-episode iteration carries the
@@ -670,10 +673,10 @@ def train_host(
     the fence the ``time.time()`` around ``sample``/``update`` measures
     dispatch, and the compute silently leaks into whichever bucket
     blocks next (historically ``env_step``, inflating the paper's
-    Fig. 4 env share).  The buckets are ``obs/trace.py`` fenced spans:
-    pass a ``tracer`` to also get the per-span Chrome trace
-    (``tracer.dump("trace.json")``); the returned profile is its
-    ``totals()``.  A ``registry`` receives each iteration record as
+    Fig. 4 env share).  The buckets are ``obs/trace.py`` fenced spans,
+    each also a ``jax.profiler`` annotation, so a profiler session
+    records them beside the device's operations; the returned profile
+    is the tracer's ``totals()`` (pass a ``tracer`` to share one).  A ``registry`` receives each iteration record as
     ``ppo_*`` metrics.
 
     ``spec`` defaults to ``env_pool.spec`` (every protocol engine
@@ -807,9 +810,9 @@ def train_host_pipelined(
     Returns ``(state, net, history, profile)``; the profile buckets are
     ``actor_wait`` (learner time blocked on the queue — env stepping
     that did NOT overlap), ``train`` and ``other`` — ``obs/trace.py``
-    fenced spans, same as ``train_host`` (pass a ``tracer`` for the
-    Chrome trace; the tracer's per-thread buffers keep the learner's
-    spans separate from any actor-side instrumentation).
+    fenced spans, same as ``train_host`` (pass a ``tracer`` to share
+    one; its totals sum over threads, and a profiler session records
+    each span on the thread that ran it).
     """
     if spec is None:
         spec = env_pool.spec

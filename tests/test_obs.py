@@ -264,22 +264,55 @@ def test_publish_pool_stats_and_json_export(tmp_path):
 # --------------------------------------------------------------------- #
 # fenced trace spans
 # --------------------------------------------------------------------- #
-def test_tracer_totals_accumulate_and_events_sorted():
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records each
+    annotation's name, thread and whether it was open around the work."""
+
+    def __init__(self):
+        self.log = []
+        self.open = set()
+        self.lock = threading.Lock()
+
+    def __call__(self, name):
+        rec = self
+
+        class _Note:
+            def __enter__(self):
+                with rec.lock:
+                    rec.log.append((name, threading.get_ident()))
+                    rec.open.add((name, threading.get_ident()))
+                return self
+
+            def __exit__(self, *exc):
+                with rec.lock:
+                    rec.open.discard((name, threading.get_ident()))
+
+        return _Note()
+
+
+def test_tracer_totals_accumulate_and_events_sorted(monkeypatch):
+    """Totals sum every span of a name, and each span is a profiler
+    annotation of its name, open for the span's whole duration."""
+    notes = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", notes)
     tr = Tracer()
+    seen = []
     with tr.span("a"):
+        seen.append(set(notes.open))
         time.sleep(0.01)
     with tr.span("a"):
         time.sleep(0.01)
-    with tr.span("b", cat="custom"):
-        pass
+    with tr.span("b"):
+        with tr.span("c"):
+            seen.append(set(notes.open))
     tot = tr.totals()
     assert tot["a"] >= 0.02
-    assert tot["b"] >= 0.0
-    evs = tr.events()
-    assert [e["name"] for e in evs] == ["a", "a", "b"]
-    assert all(e["ph"] == "X" for e in evs)
-    assert evs == sorted(evs, key=lambda e: e["ts"])
-    assert {e["cat"] for e in evs} == {"engine", "custom"}
+    assert tot["b"] >= tot["c"] >= 0.0
+    assert set(tot) == {"a", "b", "c"}
+    me = threading.get_ident()
+    assert notes.log == [("a", me), ("a", me), ("b", me), ("c", me)]
+    assert seen == [{("a", me)}, {("b", me), ("c", me)}]
+    assert notes.open == set()                  # every annotation closed
 
 
 def test_span_fence_blocks_before_close(monkeypatch):
@@ -321,7 +354,11 @@ def test_span_fence_covers_async_dispatch():
     assert tot["idle"] < tot["compute"] + 1.0   # sanity, not a perf pin
 
 
-def test_tracer_threaded_buffers_and_dump(tmp_path):
+def test_tracer_threaded_buffers_and_dump(monkeypatch):
+    """Spans of several threads sum into one total per name, and each
+    thread's span opens its annotation on that thread."""
+    notes = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", notes)
     tr = Tracer()
     # all four threads live at once: an exited thread's id can be reused
     together = threading.Barrier(4)
@@ -330,20 +367,21 @@ def test_tracer_threaded_buffers_and_dump(tmp_path):
         with tr.span("w"):
             time.sleep(0.005)
             together.wait(timeout=10)
+        with tr.span("v"):
+            pass
 
     threads = [threading.Thread(target=worker) for _ in range(4)]
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
-    tr.instant("mark")
-    assert tr.totals()["w"] >= 4 * 0.005        # sums across threads
-    assert len({e["tid"] for e in tr.events() if e["name"] == "w"}) == 4
-    path = tr.dump(str(tmp_path / "trace.json"))
-    data = json.load(open(path))
-    names = {e["name"] for e in data["traceEvents"]}
-    assert {"w", "mark"} <= names
-    assert data["displayTimeUnit"] == "ms"
+        t.join(timeout=30)
+    assert not any(t.is_alive() for t in threads)
+    tot = tr.totals()
+    assert tot["w"] >= 4 * 0.005                # sums across threads
+    assert set(tot) == {"w", "v"}
+    assert len({tid for n, tid in notes.log if n == "w"}) == 4
+    assert sorted(n for n, _ in notes.log) == ["v"] * 4 + ["w"] * 4
+    assert notes.open == set()
 
 
 # --------------------------------------------------------------------- #
@@ -399,3 +437,40 @@ def test_decode_pool_fenced_wall_and_registry():
         pytest.approx(stats.utilization)
     assert reg.counter("decode_wall_s").value(**lbl) == \
         pytest.approx(stats.wall_s)
+
+
+# --------------------------------------------------------------------- #
+# device phases: every named scope reaches the compiled program
+# --------------------------------------------------------------------- #
+_ALL_MODES = {"pool.send", "pool.select", "pool.gather", "pool.env_step",
+              "pool.telemetry", "collect", "collect.policy"}
+
+
+@pytest.mark.parametrize("task,engine,num_envs,batch,expect", [
+    ("Ant-v3", "device", 8, 8, _ALL_MODES | {"pool.writeback"}),
+    ("AntNorm-v3", "device", 16, 8,
+     _ALL_MODES | {"pool.writeback", "pool.transform"}),
+    ("Ant-v3", "device-masked", 16, 8, _ALL_MODES | {"pool.tick"}),
+], ids=["sync", "async", "masked"])
+def test_pool_phases_reach_compiled_op_names(task, engine, num_envs, batch,
+                                             expect):
+    """The random collect over a ``device`` engine, compiled: each phase
+    the mode runs names some operation's ``op_name``, which is what a
+    profile reader attributes device time by."""
+    import re
+
+    from repro.core.xla_loop import build_random_collect_fn
+    from repro.obs.trace import SCOPES
+
+    pool = repro.make(task, num_envs=num_envs, batch_size=batch,
+                      engine=engine, seed=SEED)
+    ps, ts = pool.reset(jax.random.PRNGKey(0))
+    text = build_random_collect_fn(pool, num_steps=2).lower(
+        ps, None, ts, jax.random.PRNGKey(1)).compile().as_text()
+    found = set()
+    for path in re.findall(r'op_name="([^"]*)"', text):
+        inner = [p for p in path.split("/") if p in SCOPES]
+        if inner:
+            found.add(inner[-1])
+    assert expect <= found, sorted(expect - found)
+    assert found <= set(SCOPES)
