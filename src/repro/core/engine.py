@@ -20,7 +20,11 @@ Layout: ``PoolState`` leaves keep their *logical* shapes — per-lane
 leaves are ``(N, ...)`` (partitioned over the mesh axis on dim 0, so
 each device materializes its ``N/D`` rows), per-shard scalars (``tick``,
 ``rng``, global transform state such as ``NormalizeObs`` moments) carry
-a leading ``(D, ...)`` shard dim.  Batches cross the API boundary flat
+a leading ``(D, ...)`` shard dim.  The engine's own lane rows (env
+state, action table, stored results: ``LaneRows``) live in one
+``(N, W) uint32`` slab (``core/lanes.py``), so a recv moves a block of
+lanes with one gather and one scatter; ``MeshEnvPool.lane_view`` reads
+them back as a pytree.  Batches cross the API boundary flat
 (``(M, ...)``, shard-major order); ``send`` requires batches to stay in
 the recv grouping (the standard ``send(actions, ts.env_id)`` loop
 preserves it — EnvPool's route-by-env_id contract).
@@ -64,6 +68,7 @@ from jax import lax
 from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core.lanes import LaneLayout
 from repro.core.scheduler import (
     HAS_ACTION,
     READY,
@@ -92,7 +97,7 @@ from repro.obs.trace import (
     TRANSFORM,
     WRITEBACK,
 )
-from repro.utils.pytree import pytree_dataclass, tree_gather
+from repro.utils.pytree import pytree_dataclass
 
 ENV_AXIS = "env"
 
@@ -138,21 +143,17 @@ def derive_env_keys(key: jax.Array, num_envs: int) -> tuple[jax.Array, jax.Array
 
 
 @pytree_dataclass
-class PoolState:
-    """The pool's full execution state, one pytree.
+class LaneRows:
+    """The engine's per-lane rows, each leaf with a leading lane dim.
 
-    Per-lane leaves carry a leading ``N`` dim (partitioned over the mesh
-    axis); ``tick``/``rng`` and global transform-state leaves carry a
-    leading ``(D,)`` per-shard dim.  At ``D == 1`` the per-lane layout is
-    exactly the classic single-device engine's.
+    On ``PoolState`` they are packed into one slab (``core/lanes.py``);
+    the engine unpacks the rows of the lanes it serves, and
+    ``MeshEnvPool.lane_view`` unpacks all of them for cold readers.
     """
 
-    env_states: Any            # pytree, leading dim N
-    phase: jnp.ndarray         # (N,) int32
-    actions: jnp.ndarray       # (N, *act_shape) action table
-    cost: jnp.ndarray          # (N,) int32 predicted cost of pending step
-    send_tick: jnp.ndarray     # (N,) int32 tick when action was enqueued
-    progress: jnp.ndarray      # (N,) int32 substeps done (masked mode)
+    env_states: Any            # the env's state pytree
+    actions: jnp.ndarray       # (*act_shape) action table
+    progress: jnp.ndarray      # int32 substeps done (masked mode)
     # stored results for READY envs (obs always re-derived from env state)
     r_reward: jnp.ndarray
     r_done: jnp.ndarray
@@ -161,6 +162,23 @@ class PoolState:
     r_ep_return: jnp.ndarray
     r_ep_length: jnp.ndarray
     r_cost: jnp.ndarray
+
+
+@pytree_dataclass
+class PoolState:
+    """The pool's full execution state, one pytree.
+
+    Per-lane leaves carry a leading ``N`` dim (partitioned over the mesh
+    axis); ``tick``/``rng`` and global transform-state leaves carry a
+    leading ``(D,)`` per-shard dim.  The ``LaneRows`` are packed into
+    ``lanes`` by the pool's ``LaneLayout``.
+    """
+
+    lanes: jnp.ndarray         # (N, W) uint32 packed LaneRows
+    # the scheduler's lane signals, read densely over all N
+    phase: jnp.ndarray         # (N,) int32
+    cost: jnp.ndarray          # (N,) int32 predicted cost of pending step
+    send_tick: jnp.ndarray     # (N,) int32 tick when action was enqueued
     tick: jnp.ndarray          # (D,) int32 per-shard recv counter
     rng: jax.Array             # (D, ...) per-shard rng keys
     # transform-pipeline state (core/transforms.py): one entry per
@@ -276,6 +294,11 @@ class MeshEnvPool:
         self.benv = as_batch_env(env, native=batched)
         # drivers see the TRANSFORMED spec; act_spec is never changed
         self.spec = self.pipeline.out_spec
+        # the static column layout of the lane rows (core/lanes.py), from
+        # the shapes and dtypes of a fresh block's rows; env states hold
+        # raw PRNG keys (``init_from_keys`` unwraps typed ones)
+        self.lanes = LaneLayout(jax.eval_shape(lambda: self._fresh_rows(
+            derive_env_keys(jax.random.PRNGKey(0), 1)[0])))
 
     # ------------------------------------------------------------------ #
     # per-shard <-> stacked layout plumbing (the ONLY conversion code)
@@ -325,18 +348,12 @@ class MeshEnvPool:
     # ------------------------------------------------------------------ #
     # construction / reset
     # ------------------------------------------------------------------ #
-    def _local_init(self, env_keys: jax.Array, rng: jax.Array) -> PoolState:
-        """Fresh per-shard block: every env resets; all results READY
-        (async_reset semantics, paper A.3)."""
-        env_states = self.benv.v_init_state(env_keys)
+    def _fresh_rows(self, env_keys: jax.Array) -> LaneRows:
         n = env_keys.shape[0]
         act = self.spec.act_spec
-        return PoolState(
-            env_states=env_states,
-            phase=jnp.full((n,), READY, jnp.int32),
+        return LaneRows(
+            env_states=self.benv.v_init_state(env_keys),
             actions=jnp.zeros((n,) + act.shape, act.dtype),
-            cost=jnp.zeros((n,), jnp.int32),
-            send_tick=jnp.zeros((n,), jnp.int32),
             progress=jnp.zeros((n,), jnp.int32),
             r_reward=jnp.zeros((n,), jnp.float32),
             r_done=jnp.zeros((n,), jnp.bool_),
@@ -345,6 +362,17 @@ class MeshEnvPool:
             r_ep_return=jnp.zeros((n,), jnp.float32),
             r_ep_length=jnp.zeros((n,), jnp.int32),
             r_cost=jnp.zeros((n,), jnp.int32),
+        )
+
+    def _local_init(self, env_keys: jax.Array, rng: jax.Array) -> PoolState:
+        """Fresh per-shard block: every env resets; all results READY
+        (async_reset semantics, paper A.3)."""
+        n = env_keys.shape[0]
+        return PoolState(
+            lanes=self.lanes.pack(self._fresh_rows(env_keys)),
+            phase=jnp.full((n,), READY, jnp.int32),
+            cost=jnp.zeros((n,), jnp.int32),
+            send_tick=jnp.zeros((n,), jnp.int32),
             tick=jnp.int32(0),
             rng=rng,
             tf_state=self.pipeline.init(n),
@@ -353,6 +381,10 @@ class MeshEnvPool:
 
     def _init_from_keys_impl(self, env_keys: jax.Array, rng: jax.Array
                              ) -> PoolState:
+        if jnp.issubdtype(env_keys.dtype, jax.dtypes.prng_key):
+            # the lane layout holds raw keys; the same key data draws
+            # the same numbers
+            env_keys = jax.random.key_data(env_keys)
         shard_rngs = jax.random.split(rng, self.num_shards)
 
         def init_shard(keys, rngs):
@@ -385,18 +417,22 @@ class MeshEnvPool:
     @jax.named_scope(SEND)
     def _local_send(self, ps: PoolState, actions: jnp.ndarray,
                     local_ids: jnp.ndarray) -> PoolState:
-        sel_states = tree_gather(ps.env_states, local_ids)
-        costs = self.benv.v_step_cost(sel_states, actions)
+        sel = self.lanes.gather(ps.lanes, local_ids)
+        costs = self.benv.v_step_cost(sel.env_states, actions)
         costs = jnp.clip(costs, self.spec.min_cost, self.spec.max_cost)
         ss = self.scheduler.enqueue(self._sched_view(ps), local_ids, costs)
+        # the gathered rows with the new action and progress: one row
+        # scatter writes both
+        lanes = self.lanes.scatter(
+            ps.lanes, local_ids,
+            sel.replace(actions=actions,
+                        progress=jnp.zeros(local_ids.shape, jnp.int32)),
+        )
         return ps.replace(
-            actions=ps.actions.at[local_ids].set(
-                actions.astype(ps.actions.dtype)
-            ),
+            lanes=lanes,
             phase=ss.phase,
             cost=ss.cost,
             send_tick=ss.send_tick,
-            progress=ps.progress.at[local_ids].set(0),
         )
 
     def _send_impl(self, ps: PoolState, actions: jnp.ndarray,
@@ -456,15 +492,13 @@ class MeshEnvPool:
             idx = self.scheduler.select(self._sched_view(ps), self._m_local)
 
         with jax.named_scope(GATHER):
-            sel_states = tree_gather(ps.env_states, idx)
-            sel_actions = ps.actions[idx]
-            sel_phase = ps.phase[idx]
-            need_step = sel_phase == HAS_ACTION
+            sel = self.lanes.gather(ps.lanes, idx)
+            need_step = ps.phase[idx] == HAS_ACTION
 
         with jax.named_scope(ENV_STEP):
             # batched-native step: ONE fused multi-substep call for the
             # whole block (per-lane data-dependent cost handled inside)
-            new_states, ts = self.benv.v_step(sel_states, sel_actions,
+            new_states, ts = self.benv.v_step(sel.env_states, sel.actions,
                                               need_step)
             # ONE observe pass over the post-step states serves every
             # lane: for stepped lanes ``new_states`` is the finalized
@@ -479,40 +513,33 @@ class MeshEnvPool:
         with jax.named_scope(GATHER):
             out = TimeStep(
                 obs=obs,
-                reward=jnp.where(need_step, ts.reward, ps.r_reward[idx]),
-                done=jnp.where(need_step, ts.done, ps.r_done[idx]),
-                terminated=jnp.where(need_step, ts.terminated,
-                                     ps.r_term[idx]),
-                truncated=jnp.where(need_step, ts.truncated,
-                                    ps.r_trunc[idx]),
+                reward=jnp.where(need_step, ts.reward, sel.r_reward),
+                done=jnp.where(need_step, ts.done, sel.r_done),
+                terminated=jnp.where(need_step, ts.terminated, sel.r_term),
+                truncated=jnp.where(need_step, ts.truncated, sel.r_trunc),
                 env_id=idx,
                 episode_return=jnp.where(
-                    need_step, ts.episode_return, ps.r_ep_return[idx]
+                    need_step, ts.episode_return, sel.r_ep_return
                 ),
                 episode_length=jnp.where(
-                    need_step, ts.episode_length, ps.r_ep_length[idx]
+                    need_step, ts.episode_length, sel.r_ep_length
                 ),
-                step_cost=jnp.where(need_step, ts.step_cost,
-                                    ps.r_cost[idx]),
+                step_cost=jnp.where(need_step, ts.step_cost, sel.r_cost),
             )
         with jax.named_scope(WRITEBACK):
-            env_states = jax.tree.map(
-                lambda full, upd: full.at[idx].set(upd), ps.env_states,
-                new_states
-            )
+            lanes = self.lanes.scatter(
+                ps.lanes, idx, sel.replace(
+                    env_states=new_states,
+                    r_reward=out.reward,
+                    r_done=out.done,
+                    r_term=out.terminated,
+                    r_trunc=out.truncated,
+                    r_ep_return=out.episode_return,
+                    r_ep_length=out.episode_length,
+                    r_cost=out.step_cost,
+                ))
             ss = self.scheduler.complete(self._sched_view(ps), idx)
-            ps = ps.replace(
-                env_states=env_states,
-                phase=ss.phase,
-                r_reward=ps.r_reward.at[idx].set(out.reward),
-                r_done=ps.r_done.at[idx].set(out.done),
-                r_term=ps.r_term.at[idx].set(out.terminated),
-                r_trunc=ps.r_trunc.at[idx].set(out.truncated),
-                r_ep_return=ps.r_ep_return.at[idx].set(out.episode_return),
-                r_ep_length=ps.r_ep_length.at[idx].set(out.episode_length),
-                r_cost=ps.r_cost.at[idx].set(out.step_cost),
-                tick=ss.tick,
-            )
+            ps = ps.replace(lanes=lanes, phase=ss.phase, tick=ss.tick)
         if self.obs:
             ps = ps.replace(
                 telemetry=record_serve(
@@ -529,21 +556,23 @@ class MeshEnvPool:
     # masked (event-driven tick) mode — the literal-semantics ablation
     # ------------------------------------------------------------------ #
     @jax.named_scope(TICK)
-    def _tick(self, ps: PoolState) -> PoolState:
-        """Advance every HAS_ACTION lane one substep (idle lanes masked)."""
+    def _tick(self, ps: PoolState, rows: LaneRows
+              ) -> tuple[PoolState, LaneRows]:
+        """Advance every HAS_ACTION lane one substep (idle lanes masked),
+        over the unpacked lane rows."""
         busy = ps.phase == HAS_ACTION
-        starting = busy & (ps.progress == 0)
+        starting = busy & (rows.progress == 0)
         # clear accumulators at the start of a step
-        pre = self.benv.v_pre_step(ps.env_states)
+        pre = self.benv.v_pre_step(rows.env_states)
         states = jax.tree.map(
             lambda p, s: jnp.where(
                 starting.reshape(starting.shape + (1,) * (p.ndim - 1)), p, s
             ),
             pre,
-            ps.env_states,
+            rows.env_states,
         )
-        stepped = self.benv.v_substep(states, ps.actions)
-        running = busy & (ps.progress < ps.cost)
+        stepped = self.benv.v_substep(states, rows.actions)
+        running = busy & (rows.progress < ps.cost)
         states = jax.tree.map(
             lambda n, o: jnp.where(
                 running.reshape(running.shape + (1,) * (n.ndim - 1)), n, o
@@ -551,7 +580,7 @@ class MeshEnvPool:
             stepped,
             states,
         )
-        progress = jnp.where(running, ps.progress + 1, ps.progress)
+        progress = jnp.where(running, rows.progress + 1, rows.progress)
         finished = busy & (progress >= ps.cost)
 
         fin_states, fin_ts = self.benv.v_finalize(states, ps.cost)
@@ -562,18 +591,22 @@ class MeshEnvPool:
             fin_states,
             states,
         )
-        new = ps.replace(
+        rows = rows.replace(
             env_states=states,
             progress=progress,
+            r_reward=jnp.where(finished, fin_ts.reward, rows.r_reward),
+            r_done=jnp.where(finished, fin_ts.done, rows.r_done),
+            r_term=jnp.where(finished, fin_ts.terminated, rows.r_term),
+            r_trunc=jnp.where(finished, fin_ts.truncated, rows.r_trunc),
+            r_ep_return=jnp.where(finished, fin_ts.episode_return,
+                                  rows.r_ep_return),
+            r_ep_length=jnp.where(finished, fin_ts.episode_length,
+                                  rows.r_ep_length),
+            r_cost=jnp.where(finished, ps.cost, rows.r_cost),
+        )
+        new = ps.replace(
             phase=jnp.where(finished, READY, ps.phase),
             send_tick=jnp.where(finished, ps.tick, ps.send_tick),
-            r_reward=jnp.where(finished, fin_ts.reward, ps.r_reward),
-            r_done=jnp.where(finished, fin_ts.done, ps.r_done),
-            r_term=jnp.where(finished, fin_ts.terminated, ps.r_term),
-            r_trunc=jnp.where(finished, fin_ts.truncated, ps.r_trunc),
-            r_ep_return=jnp.where(finished, fin_ts.episode_return, ps.r_ep_return),
-            r_ep_length=jnp.where(finished, fin_ts.episode_length, ps.r_ep_length),
-            r_cost=jnp.where(finished, ps.cost, ps.r_cost),
         )
         if self.obs:
             # substep accounting belongs to the tick that finished the
@@ -581,35 +614,39 @@ class MeshEnvPool:
             new = new.replace(
                 telemetry=record_finished(ps.telemetry, finished, ps.cost)
             )
-        return new
+        return new, rows
 
     def _recv_masked(self, ps: PoolState) -> tuple[PoolState, TimeStep]:
         m = self._m_local
 
-        def not_enough(s: PoolState):
-            return jnp.sum(s.phase == READY) < m
+        def not_enough(carry):
+            return jnp.sum(carry[0].phase == READY) < m
 
         with jax.named_scope(TICK):
-            ps = lax.while_loop(not_enough, self._tick, ps)
+            # the loop body keeps dense per-leaf arithmetic: unpack the
+            # slab once before the loop and pack it once after
+            rows = self.lanes.unpack(ps.lanes)
+            ps, rows = lax.while_loop(not_enough, lambda c: self._tick(*c),
+                                      (ps, rows))
+            ps = ps.replace(lanes=self.lanes.pack(rows))
         # completion order ≈ send_tick order among READY (policy-
         # independent by the select_ready contract)
         idx = self.scheduler.select_ready(self._sched_view(ps), m)
         with jax.named_scope(GATHER):
-            sel_states = tree_gather(ps.env_states, idx)
+            sel = self.lanes.gather(ps.lanes, idx)
         with jax.named_scope(ENV_STEP):
-            obs = self.benv.v_observe(sel_states)
-        with jax.named_scope(GATHER):
-            out = TimeStep(
-                obs=obs,
-                reward=ps.r_reward[idx],
-                done=ps.r_done[idx],
-                terminated=ps.r_term[idx],
-                truncated=ps.r_trunc[idx],
-                env_id=idx,
-                episode_return=ps.r_ep_return[idx],
-                episode_length=ps.r_ep_length[idx],
-                step_cost=ps.r_cost[idx],
-            )
+            obs = self.benv.v_observe(sel.env_states)
+        out = TimeStep(
+            obs=obs,
+            reward=sel.r_reward,
+            done=sel.r_done,
+            terminated=sel.r_term,
+            truncated=sel.r_trunc,
+            env_id=idx,
+            episode_return=sel.r_ep_return,
+            episode_length=sel.r_ep_length,
+            step_cost=sel.r_cost,
+        )
         ss = self.scheduler.complete(self._sched_view(ps), idx)
         if self.obs:
             # wait since the step COMPLETED (``_tick`` stamps send_tick
@@ -704,6 +741,22 @@ class MeshEnvPool:
         """Sync-style reset: init + drain the first batch of M results."""
         with jax.profiler.TraceAnnotation("pool.reset"):
             return self._jit_reset(key)
+
+    # ------------------------------------------------------------------ #
+    # cold readers of the lane rows
+    # ------------------------------------------------------------------ #
+    def lane_view(self, ps: PoolState) -> LaneRows:
+        """Every lane's rows unpacked from the slab, ``(N, ...)`` leaves:
+        env states, the action table and the stored results.  A view for
+        readers off the hot path (tests, inspection); the pool keeps no
+        second copy."""
+        if _traced(ps):
+            return self.lanes.unpack(ps.lanes)
+        return self._jit_lane_view(ps.lanes)
+
+    @functools.cached_property
+    def _jit_lane_view(self):
+        return jax.jit(self.lanes.unpack)
 
     # ------------------------------------------------------------------ #
     # telemetry snapshot (core/protocol.py ``stats()`` contract)
@@ -855,6 +908,7 @@ def make_pool(
 
 __all__ = [
     "ENV_AXIS",
+    "LaneRows",
     "MeshEnvPool",
     "PoolState",
     "derive_env_keys",

@@ -99,8 +99,9 @@ def test_env_id_routing():
     # env k's dynamics (check obs corresponds to stored env state)
     a = env.sample_actions(jax.random.PRNGKey(5), 4)
     ps2, ts2 = pool.step(ps, a, ts.env_id)
+    env_states = pool.lane_view(ps2).env_states
     for j, env_id in enumerate(np.asarray(ts2.env_id)):
-        state_j = jax.tree.map(lambda x: x[env_id], ps2.env_states)
+        state_j = jax.tree.map(lambda x: x[env_id], env_states)
         np.testing.assert_allclose(
             np.asarray(env.observe(state_j)), np.asarray(ts2.obs[j]), rtol=1e-6
         )

@@ -214,10 +214,10 @@ def test_xla_handle_is_seedable(maker):
     h0b, *_ = pool.xla(seed=0)
     h7, *_ = pool.xla(seed=7)
     hk, *_ = pool.xla(key=jax.random.PRNGKey(7))
-    t0a = jax.tree.leaves(h0a.env_states)[0]
-    t0b = jax.tree.leaves(h0b.env_states)[0]
-    t7 = jax.tree.leaves(h7.env_states)[0]
-    tk = jax.tree.leaves(hk.env_states)[0]
+    t0a = jax.tree.leaves(pool.lane_view(h0a).env_states)[0]
+    t0b = jax.tree.leaves(pool.lane_view(h0b).env_states)[0]
+    t7 = jax.tree.leaves(pool.lane_view(h7).env_states)[0]
+    tk = jax.tree.leaves(pool.lane_view(hk).env_states)[0]
     np.testing.assert_array_equal(np.asarray(t0a), np.asarray(t0b))
     np.testing.assert_array_equal(np.asarray(t7), np.asarray(tk))
     assert not np.array_equal(np.asarray(t0a), np.asarray(t7))
