@@ -32,19 +32,14 @@ preserves it — EnvPool's route-by-env_id contract).
 Determinism: per-env init keys derive from the *global* pool key
 (``derive_env_keys``), so every env's *trajectory* (its per-env
 reward/done/obs stream) is independent of the mesh size at every D.
-Block *emission order* has two regimes: async blocks are emitted in
-per-shard selection order (at D=1 exactly the classic single-device
-engine, golden-pinned); sync blocks on a multi-shard mesh are
-canonicalized to env-id order, which makes the shard-major
-concatenation identical for EVERY D > 1 regardless of per-shard top-k
-cost ordering, while the degenerate mesh keeps the classic
-single-device priority order (also golden-pinned — the atari stream has
-variable frameskip cost and is NOT env-id-sorted).  For fixed-cost
-tasks the two orders coincide and sync streams are bitwise-identical at
-all mesh sizes (tests/test_sharded_pool.py, tests/_sharded_check.py);
-for variable-cost sync tasks, D=1 may order blocks differently than
-D>1 — scale-out comparisons should align by ``env_id`` (as every
-conformance test does).
+Block *emission order*: async blocks are emitted in per-shard
+selection order (at D=1 exactly the classic single-device engine).
+Sync blocks are emitted in env-id order at every D, as gym's and
+EnvPool's sync ``step(actions)`` contract requires: row ``i`` of every
+block is lane ``i``, whatever the per-shard top-k cost ordering, so a
+trainer that stacks sync blocks over time (GAE, ``rl/ppo.py``) reads
+each lane's own stream, and sync streams are bitwise-identical at all
+mesh sizes (tests/test_sharded_pool.py, tests/_sharded_check.py).
 
 Three execution modes (identical to the classic engine):
   * ``sync``   — step all N each recv (gym.vector semantics, M = N).
@@ -678,16 +673,9 @@ class MeshEnvPool:
             local, ts = self._local_recv(self._local_view(ps_s))
             shard = lax.axis_index(self.axis_name).astype(jnp.int32)
             ts = ts.replace(env_id=ts.env_id + shard * n_local)
-            if self.mode == "sync" and self.num_shards > 1:
-                # multi-shard sync blocks are canonicalized to env-id
-                # order so the shard-major concatenation is independent
-                # of per-shard top-k cost ordering AND identical for
-                # every D > 1 (a shard-local permutation, still no
-                # comms).  The degenerate mesh keeps the classic
-                # single-device priority order instead — the atari
-                # golden pins it (variable cost, not env-id-sorted), so
-                # D=1 vs D>1 sync ordering coincides only for
-                # fixed-cost tasks; see the module docstring.
+            if self.mode == "sync":
+                # sync blocks in env-id order (a shard-local
+                # permutation, no comms): row i is lane i at every D
                 with jax.named_scope(WRITEBACK):
                     order = jnp.argsort(ts.env_id)
                     ts = jax.tree.map(lambda x: x[order], ts)

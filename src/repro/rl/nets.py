@@ -99,23 +99,19 @@ class ActorCritic:
 
     # ---------------- distribution ops ----------------------------- #
     def sample(self, p, obs, key):
-        """Returns (action, logp, value, entropy)."""
+        """Returns (action, logp, value, logits_or_mean)."""
         pi, v = self.forward(p, obs)
         if self.discrete:
             a = jax.random.categorical(key, pi)
             logp = jax.nn.log_softmax(pi)[jnp.arange(a.shape[0]), a]
-            ent = -jnp.sum(jax.nn.softmax(pi) * jax.nn.log_softmax(pi), -1)
-            return a.astype(self.spec.act_spec.dtype), logp, v, ent
+            return a.astype(self.spec.act_spec.dtype), logp, v, pi
         std = jnp.exp(p["log_std"])
         noise = jax.random.normal(key, pi.shape)
         a = pi + std * noise
         logp = -0.5 * jnp.sum(
             ((a - pi) / std) ** 2 + 2 * p["log_std"] + jnp.log(2 * jnp.pi), -1
         )
-        ent = jnp.sum(p["log_std"] + 0.5 * jnp.log(2 * jnp.pi * jnp.e)) * jnp.ones(
-            a.shape[0]
-        )
-        return a.astype(jnp.float32), logp, v, ent
+        return a.astype(jnp.float32), logp, v, pi
 
     def logp_entropy(self, p, obs, actions):
         pi, v = self.forward(p, obs)

@@ -61,6 +61,7 @@ same call works over `device`, `device-masked`, `device-sharded`,
 from __future__ import annotations
 
 import dataclasses
+import math
 import threading
 import time
 from typing import Any, Callable
@@ -109,7 +110,14 @@ class PPOState:
     step: jnp.ndarray
 
 
-def make_ppo_update(net: ActorCritic, cfg: PPOConfig, total_updates: int):
+def make_ppo_update(net: ActorCritic, cfg: PPOConfig, total_updates: int,
+                    keep_steps: bool = False):
+    """``(opt, update)``: ``update(state, rollout, key) -> (state,
+    metrics)`` runs every epoch's minibatch steps.  With ``keep_steps``,
+    ``metrics["steps"]`` holds ``first``, the learner state after the
+    update's first minibatch step, and ``last``, the state before its
+    last one: what it takes to check those two steps one at a time,
+    each from the program's own parameters."""
     opt = adamw(b1=0.9, b2=0.999, eps=1e-5, weight_decay=0.0,
                 clip_norm=cfg.max_grad_norm)
     lr_fn = (linear_decay(cfg.lr, total_updates) if cfg.anneal_lr
@@ -144,28 +152,41 @@ def make_ppo_update(net: ActorCritic, cfg: PPOConfig, total_updates: int):
         flat = {k: v.reshape((-1,) + v.shape[2:]) for k, v in rollout.items()}
         B = flat["obs"].shape[0]
         mb = B // cfg.minibatches
+        step0, n_steps = state.step, cfg.epochs * cfg.minibatches
 
         def epoch(carry, ek):
-            state = carry
             perm = jax.random.permutation(ek, B)
 
-            def mb_step(state, i):
+            def mb_step(carry, i):
+                state, kept = carry
                 idx = jax.lax.dynamic_slice_in_dim(perm, i * mb, mb)
                 batch = {k: v[idx] for k, v in flat.items()}
                 (loss, metrics), grads = grad_fn(state.params, batch)
                 lr = lr_fn(state.step)
                 params, opt_state = opt.update(grads, state.opt, state.params, lr)
-                return PPOState(params, opt_state, state.step + 1), (loss, metrics)
+                new = PPOState(params, opt_state, state.step + 1)
+                if keep_steps:
+                    k = state.step - step0
+                    pick = lambda c, a, b: jax.tree.map(  # noqa: E731
+                        lambda x, y: jnp.where(c, y, x), a, b)
+                    kept = {"first": pick(k == 0, kept["first"], new),
+                            "last": pick(k == n_steps - 1, kept["last"],
+                                         state)}
+                return (new, kept), (loss, metrics)
 
-            state, (losses, metrics) = jax.lax.scan(
-                mb_step, state, jnp.arange(cfg.minibatches)
+            carry, (losses, metrics) = jax.lax.scan(
+                mb_step, carry, jnp.arange(cfg.minibatches)
             )
-            return state, (losses, metrics)
+            return carry, (losses, metrics)
 
         keys = jax.random.split(key, cfg.epochs)
-        state, (losses, metrics) = jax.lax.scan(epoch, state, keys)
+        kept = {"first": state, "last": state} if keep_steps else None
+        (state, kept), (losses, metrics) = jax.lax.scan(
+            epoch, (state, kept), keys)
         out = {k: jnp.mean(v) for k, v in metrics.items()}
         out["loss"] = jnp.mean(losses)
+        if keep_steps:
+            out["steps"] = kept
         return state, out
 
     return opt, update
@@ -247,11 +268,49 @@ def _record(history: list[dict], rec: dict, episodes: int, ep_sum: float,
 # --------------------------------------------------------------------- #
 # fully on-device driver
 # --------------------------------------------------------------------- #
+CHECKSUM_MUL = 2654435761     # odd: every element position weighs in
+# the served rows a step checksums: one in CHECKSUM_STRIDE, a different
+# one each step (on a v5e, widening and summing every 4x84x84 uint8
+# stack of a 1024-lane step takes about 2.6 ms, 14% of a Pong update)
+CHECKSUM_STRIDE = 16
+
+
+def obs_checksum(obs: jnp.ndarray) -> jnp.ndarray:
+    """One exact ``uint32`` per row of a served block of integer
+    observations: the sum, modulo 2**32, of the row's elements each
+    times a weight of its position.  Equal rows give equal sums on every
+    backend, and a moved, dropped or altered element changes the sum."""
+    x = obs.reshape(obs.shape[0], -1).astype(jnp.uint32)
+    w = (jnp.arange(1, x.shape[1] + 1, dtype=jnp.uint32)
+         * jnp.uint32(CHECKSUM_MUL))
+    return jnp.sum(x * w, axis=1, dtype=jnp.uint32)
+
+
+def checksum_rows(m: int, t) -> jnp.ndarray:
+    """The rows of an ``m``-row block that step ``t`` checksums: ``t mod
+    s``, ``s + t mod s``, ... with ``s = gcd(CHECKSUM_STRIDE, m)``."""
+    s = math.gcd(CHECKSUM_STRIDE, m)
+    return jnp.arange(m // s) * s + t % s
+
+
 def make_train_step(pool, cfg: PPOConfig, net: ActorCritic, update):
     """``train_device``'s program, jitted and not yet compiled:
-    ``train_step(state, ps, ts, kc, ku) -> (state, ps, ts, metrics)``.
-    Public so that a caller can lower or compile ahead of time the exact
-    program ``train_device`` runs, to read its memory and its kernels."""
+    ``train_step(state, ps, ts, kc, ku) -> (state, ps, ts, metrics,
+    record)``.  Public so that a caller can lower or compile ahead of
+    time the exact program ``train_device`` runs, to read its memory
+    and its kernels.
+
+    ``record`` holds, per rollout step and served row (``(T, M, ...)``;
+    a sync pool serves row ``i`` as lane ``i``): the action, the
+    policy's logits (or mean), the value, the reward and done served
+    after the action; ``obs_sum`` ``(T, M / s)``, ``obs_checksum`` of
+    the observations served with them in the rows ``checksum_rows``
+    picks; ``last_values`` ``(M,)``, GAE's bootstrap; and, where
+    ``update`` keeps them (``make_ppo_update(keep_steps=True)``),
+    ``steps``, the learner states around the update's first and last
+    minibatch steps.  Some tens of MB per update: what it takes to check
+    the update against a replay from a copy of the state it started
+    from."""
 
     def train_step(state, ps, ts, kc, ku):
         """ONE fused collect+update: the rollout scan and the PPO epochs
@@ -259,20 +318,24 @@ def make_train_step(pool, cfg: PPOConfig, net: ActorCritic, update):
         the env SoA buffers are updated in place and never leave the
         mesh; ``ps`` stays sharded through the entire body."""
 
-        def one_step(carry, k):
+        def one_step(carry, xs):
             ps, ts = carry
-            a, logp, v, _ = net.sample(state.params, ts.obs, k)
+            k, t = xs
+            a, logp, v, pi = net.sample(state.params, ts.obs, k)
             ps, new_ts = pool.step(ps, a, ts.env_id)
+            rows = checksum_rows(new_ts.obs.shape[0], t)
             data = {
                 "obs": ts.obs, "actions": a, "logp": logp, "values": v,
-                "rewards": new_ts.reward, "dones": new_ts.done,
+                "pi": pi, "rewards": new_ts.reward, "dones": new_ts.done,
                 "ep_ret": new_ts.episode_return,
+                "obs_sum": obs_checksum(new_ts.obs[rows]),
             }
             return (ps, new_ts), data
 
         with jax.named_scope(PPO_COLLECT):
             keys = jax.random.split(kc, cfg.num_steps)
-            (ps, ts), traj = jax.lax.scan(one_step, (ps, ts), keys)
+            (ps, ts), traj = jax.lax.scan(
+                one_step, (ps, ts), (keys, jnp.arange(cfg.num_steps)))
         with jax.named_scope(PPO_GAE):
             _, last_v = net.forward(state.params, ts.obs)
             adv, ret = gae(traj["rewards"], traj["values"], traj["dones"],
@@ -282,37 +345,40 @@ def make_train_step(pool, cfg: PPOConfig, net: ActorCritic, update):
             "logp": traj["logp"], "values": traj["values"],
             "adv": adv, "ret": ret,
         }
+        record = {k: traj[k] for k in ("actions", "pi", "values", "rewards",
+                                       "dones", "obs_sum")}
+        record["last_values"] = last_v
         with jax.named_scope(PPO_UPDATE):
             state, metrics = update(state, rollout, ku)
+        if "steps" in metrics:
+            record["steps"] = metrics.pop("steps")
         # episode stats reduced in-graph: only scalars cross to the host.
         # The count and sum cross separately — the mean is formed host-
         # side (``_record``) so a zero-episode iteration carries the
         # previous value forward instead of emitting ``0/0 = NaN``.
         episodes, ep_sum = _episode_metrics(traj["dones"], traj["ep_ret"])
         metrics = dict(metrics, episodes=episodes, ep_sum=ep_sum)
-        return state, ps, ts, metrics
+        return state, ps, ts, metrics, record
 
     return jax.jit(train_step, donate_argnums=(0, 1, 2))
 
 
-def train_device(
-    pool: "DeviceEnvPool | Any",   # any mesh engine (device/device-sharded)
-    cfg: PPOConfig,
-    seed: int = 0,
-    log_fn: Callable[[dict], None] | None = None,
-    hidden: tuple[int, ...] = (256, 128, 64),
-):
+def init_train_device(pool, cfg: PPOConfig, seed: int = 0,
+                      hidden: tuple[int, ...] = (256, 128, 64)):
+    """``train_device``'s set-up: ``(net, state, train_step, (ps, ts),
+    key)`` — the policy, its learner state placed on the pool's mesh,
+    the jitted ``train_step``, the pool's first state, and the key each
+    update splits its own from (``key, kc, ku = split(key, 3)``)."""
     net = ActorCritic(pool.spec, hidden=hidden)
     key = jax.random.PRNGKey(seed)
     key, k_init, k_pool = jax.random.split(key, 3)
     params = net.init(k_init)
 
-    M = pool.batch_size
-    steps_per_iter = cfg.num_steps * M
+    steps_per_iter = cfg.num_steps * pool.batch_size
     total_updates = max(
         1, cfg.total_steps // steps_per_iter
     ) * cfg.epochs * cfg.minibatches
-    opt, update = make_ppo_update(net, cfg, total_updates)
+    opt, update = make_ppo_update(net, cfg, total_updates, keep_steps=True)
     state = PPOState(params=params, opt=opt.init(params), step=jnp.int32(0))
 
     # policy placement (distributed/sharding.py): replicated across the
@@ -343,12 +409,25 @@ def train_device(
     ps, ts = pool.reset(k_pool)
     if hasattr(pool, "device_put"):
         ps = pool.device_put(ps)   # pin the env state to the mesh layout
+    return net, state, train_step, (ps, ts), key
+
+
+def train_device(
+    pool: "DeviceEnvPool | Any",   # any mesh engine (device/device-sharded)
+    cfg: PPOConfig,
+    seed: int = 0,
+    log_fn: Callable[[dict], None] | None = None,
+    hidden: tuple[int, ...] = (256, 128, 64),
+):
+    net, state, train_step, (ps, ts), key = init_train_device(
+        pool, cfg, seed, hidden)
+    steps_per_iter = cfg.num_steps * pool.batch_size
     n_iters = max(1, cfg.total_steps // steps_per_iter)
     history = []
     t0 = time.time()
     for it in range(n_iters):
         key, kc, ku = jax.random.split(key, 3)
-        state, ps, ts, metrics = train_step(state, ps, ts, kc, ku)
+        state, ps, ts, metrics, _ = train_step(state, ps, ts, kc, ku)
         episodes = int(metrics.pop("episodes"))
         ep_sum = float(metrics.pop("ep_sum"))
         rec = {

@@ -16,6 +16,8 @@ import pytest
 
 from repro.core.registry import make
 
+from _lanes import by_lane
+
 TASK = "TokenCopy-v0"
 N = 8
 STEPS = 10
@@ -183,7 +185,7 @@ def test_unified_engine_mesh1_sharded_matches_device_goldens(tag, n, m):
     """The engine-unification contract: ``device-sharded`` at mesh 1 is
     the SAME class over the SAME degenerate mesh as ``device``, so it
     must reproduce the device goldens bitwise — including sync emission
-    order (no per-shard canonicalization on the 1-shard mesh)."""
+    order (env-id order at every mesh size)."""
     ids, rew, done, obs = golden_device_stream(
         "device-sharded", n, m, num_shards=1
     )
@@ -195,9 +197,10 @@ def test_unified_engine_mesh1_sharded_matches_device_goldens(tag, n, m):
 
 def test_unified_engine_mesh1_sharded_matches_atari_golden():
     """Same unification check on the second golden: the default-pipeline
-    Pong stream (FrameStack(4) fused in-engine, variable frameskip cost
-    — emission order is NOT env-id-sorted at steps 8/20, which pins
-    that mesh-1 keeps the classic priority order)."""
+    Pong stream (FrameStack(4) fused in-engine, variable frameskip
+    cost).  Compared lane by lane: the golden's blocks came in priority
+    order (not env-id-sorted at steps 8 and 20), a sync block now comes
+    in env-id order at every mesh size."""
     golden = np.load(
         os.path.join(os.path.dirname(os.path.abspath(__file__)),
                      "golden_atari_stream.npz")
@@ -214,12 +217,11 @@ def test_unified_engine_mesh1_sharded_matches_atari_golden():
         recs.append((np.asarray(ts.env_id), np.asarray(ts.reward),
                      np.asarray(ts.done), np.asarray(ts.step_cost),
                      np.asarray(ts.obs)))
-    ids, rew, done, cost, obs = (np.stack(x) for x in zip(*recs))
-    np.testing.assert_array_equal(ids, golden["ids"])
-    np.testing.assert_array_equal(rew, golden["rew"])
-    np.testing.assert_array_equal(done, golden["done"])
-    np.testing.assert_array_equal(cost, golden["cost"])
-    np.testing.assert_array_equal(obs, golden["obs_stack"])
+    got = by_lane(*(np.stack(x) for x in zip(*recs)))
+    want = by_lane(*(golden[k] for k in ("ids", "rew", "done", "cost",
+                                         "obs_stack")))
+    for name, g, w in zip(("ids", "rew", "done", "cost", "obs"), got, want):
+        np.testing.assert_array_equal(g, w, err_msg=name)
 
 
 def test_scanned_collect_donates_pool_state():

@@ -159,3 +159,25 @@ def test_validation_errors():
         DeviceEnvPool(env, 4, 2, mode="sync")
     with pytest.raises(ValueError):
         DeviceEnvPool(env, 4, 4, mode="weird")
+
+
+def test_sync_blocks_come_in_lane_order_at_one_shard():
+    """A sync recv serves every lane, in env-id order at every mesh size
+    (gym's and EnvPool's sync ``step`` contract), though Pong's step cost
+    varies (+2 frames after a point, +3 on a reset) and so reorders the
+    scheduler's priority from one step to the next."""
+    import repro
+
+    n = 16
+    pool = repro.make("PongClassic-v5", num_envs=n, engine="device", seed=3)
+    assert pool.num_shards == 1 and pool.mode == "sync"
+    ps, ts = pool.reset(jax.random.PRNGKey(3))
+    step = jax.jit(pool.step)
+    costs = []
+    for t in range(60):
+        np.testing.assert_array_equal(ts.env_id, np.arange(n), f"step {t}")
+        a = jnp.asarray((np.arange(n) * 5 + t) % 6, jnp.int32)
+        ps, ts = step(ps, a, ts.env_id)
+        costs.append(np.asarray(ts.step_cost))
+    # the costs did vary within steps: priority order alone would differ
+    assert sum(len(set(c)) > 1 for c in costs) >= 10

@@ -20,6 +20,8 @@ from repro.envs.atari_like import AtariLike, AtariLikeBatch
 from repro.kernels.backend import BACKENDS, resolve_backend
 from repro.kernels.image import ops, ref
 
+from _lanes import by_lane
+
 SEED = 0
 # every off-TPU backend must agree bitwise in DIRECT calls (auto
 # resolves to vmap here; keep it in the sweep so the default is pinned)
@@ -282,12 +284,14 @@ def classic_stream(steps=32, n=4, engine="device", **kw):
 def test_classic_pipeline_golden_dynamics():
     """The RGB render + in-engine image pipeline must reproduce the
     golden reward/done/cost streams bitwise: rendering is observe-only,
-    so upgrading the observation path cannot perturb dynamics."""
+    so upgrading the observation path cannot perturb dynamics.  Compared
+    lane by lane: the golden's one-shard sync blocks came in priority
+    order, a sync block now comes in env-id order."""
     ids, rew, done, cost, obs = classic_stream()
-    np.testing.assert_array_equal(ids, GOLDEN["ids"])
-    np.testing.assert_array_equal(rew, GOLDEN["rew"])
-    np.testing.assert_array_equal(done, GOLDEN["done"])
-    np.testing.assert_array_equal(cost, GOLDEN["cost"])
+    got = by_lane(ids, rew, done, cost)
+    want = by_lane(*(GOLDEN[k] for k in ("ids", "rew", "done", "cost")))
+    for name, g, w in zip(("ids", "rew", "done", "cost"), got, want):
+        np.testing.assert_array_equal(g, w, err_msg=name)
     assert obs.shape == (32, 4, 4, 84, 84) and obs.dtype == np.uint8
     # the processed screen is not degenerate: sprites survive the
     # grayscale+resize (more than one luma level per frame)

@@ -247,3 +247,40 @@ def test_ppo_async_pool_runs():
     _, _, hist = train_device(pool, cfg, seed=0, hidden=(32, 32))
     assert len(hist) >= 10
     assert all(np.isfinite(h["loss"]) for h in hist)
+
+
+def test_train_step_rows_stay_with_their_lanes():
+    """``make_train_step`` stacks step t's served rows (obs, actions,
+    values) with step t+1's (rewards, dones): on a sync pool row i is
+    lane i at every step, so GAE runs down each lane's own column.  The
+    pool here reports, as each row's reward, its env id and step cost."""
+    import repro
+    from repro.rl.nets import ActorCritic
+    from repro.rl.ppo import PPOConfig, make_ppo_update, make_train_step
+
+    n, steps = 16, 40
+    pool = repro.make("PongClassic-v5", num_envs=n, seed=5)
+
+    class LaneTagged:
+        def step(self, ps, a, ids):
+            ps, ts = pool.step(ps, a, ids)
+            tag = ts.env_id * 16 + ts.step_cost
+            return ps, ts.replace(reward=tag.astype(jnp.float32))
+
+    cfg = PPOConfig(num_steps=steps, epochs=1, minibatches=1, clip=0.1)
+    net = ActorCritic(pool.spec)
+    opt, update = make_ppo_update(net, cfg, 1)
+    params = net.init(jax.random.PRNGKey(0))
+    from repro.rl.ppo import PPOState
+
+    state = PPOState(params, opt.init(params), jnp.int32(0))
+    ps, ts = pool.reset(jax.random.PRNGKey(5))
+    np.testing.assert_array_equal(ts.env_id, np.arange(n))
+    step = make_train_step(LaneTagged(), cfg, net, update)
+    k = jax.random.PRNGKey(1)
+    _, _, ts, _, record = step(state, ps, ts, k, k)
+    tag = np.asarray(record["rewards"]).astype(np.int64)
+    np.testing.assert_array_equal(tag // 16, np.tile(np.arange(n), (steps, 1)))
+    np.testing.assert_array_equal(ts.env_id, np.arange(n))
+    assert sum(len(set(row)) > 1 for row in tag % 16) >= 5
+    assert record["obs_sum"].shape == (steps, 1)      # one row in 16
