@@ -58,7 +58,7 @@ def _flatten_to(x: jnp.ndarray, image_ndim: int):
 
 @functools.partial(jax.jit, static_argnames=("backend", "block_n"))
 def grayscale(rgb: jnp.ndarray, *, backend: str = "auto",
-              block_n: int = 4) -> jnp.ndarray:
+              block_n: int = 8) -> jnp.ndarray:
     """(..., H, W, 3) uint8 RGB -> (..., H, W) uint8 ALE luma."""
     if rgb.ndim < 3 or rgb.shape[-1] != 3:
         raise ValueError(f"grayscale wants (..., H, W, 3); got {rgb.shape}")

@@ -18,7 +18,7 @@ from repro.core.transforms import (
 )
 from repro.envs.atari_like import AtariLike, AtariLikeBatch
 from repro.kernels.backend import BACKENDS, resolve_backend
-from repro.kernels.image import ops, ref
+from repro.kernels.image import kernel, ops, ref
 
 from _lanes import by_lane
 
@@ -170,6 +170,42 @@ def test_pong_render_backends_bitwise():
     for color in (ref.PONG_BG, ref.PONG_PLAYER, ref.PONG_ENEMY,
                   ref.PONG_BALL):
         assert (px == np.array(color)).all(axis=1).any(), color
+
+
+def _direct_call(name, rng, n):
+    """(the kernel's direct call, its arguments, ref.py's answer)."""
+    img = jnp.asarray(rand_u8(rng, (n, 37, 29)))
+    if name == "grayscale":
+        rgb = jnp.asarray(rand_u8(rng, (n, 37, 29, 3)))
+        return (lambda x: kernel.grayscale_batch(x, block_n=4), (rgb,),
+                ref.grayscale_reference(rgb))
+    if name == "resize":
+        return (lambda x: kernel.resize_batch(x, 17, 13), (img,),
+                ref.resize_reference(img, 17, 13, "area"))
+    if name == "crop":
+        return (lambda x: kernel.crop_batch(x, 5, 2, 17, 19, block_n=4),
+                (img,), ref.crop_reference(img, 5, 2, 17, 19))
+    state = [rng.uniform(lo, hi, n).astype(np.float32)
+             for lo, hi in ((0, 84), (0, 84), (6, 78), (6, 78))]
+    return (lambda *v: kernel.pong_render_batch(*v, block_n=4), state,
+            ref.pong_render_reference(*state))
+
+
+@pytest.mark.parametrize("name", ["crop", "grayscale", "pong_render",
+                                  "resize"])
+def test_kernel_direct_call_is_uint8(name):
+    """Called directly in interpret mode, at a batch (5) that is no
+    multiple of the block (4), each kernel reads and writes its images
+    as uint8 at its own boundary and returns ref.py's answer bitwise."""
+    fn, args, want = _direct_call(name, np.random.default_rng(SEED), 5)
+    got = fn(*args)
+    assert got.dtype == jnp.uint8
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    (call,) = [e for e in jax.make_jaxpr(fn)(*args).eqns
+               if e.primitive.name == "pallas_call"]
+    images = [v.aval for v in call.invars + call.outvars
+              if v.aval.ndim == 3 and v.aval.shape[-1] > 1]
+    assert images and {a.dtype for a in images} == {np.dtype(np.uint8)}
 
 
 def test_atari_rgb_pipeline_composes():

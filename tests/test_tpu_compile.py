@@ -11,6 +11,7 @@ TPU library only in the worker that runs this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -114,3 +115,38 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 
     compiled = CASES[name](s).compile()
     assert KERNEL in compiled.as_text()
+
+
+def test_image_chain_hands_uint8_between_kernels(one_chip):
+    """PongClassic-v5's observation path at N=1024 (render -> grayscale
+    -> area resize through ``ops.py``) compiles to exactly its three
+    kernels, each reading and writing its images as uint8, with no
+    convert between them."""
+    def chain(bx, by, py, ey):
+        screens = image.pong_render(bx, by, py, ey, backend="pallas")
+        gray = image.grayscale(screens, backend="pallas")
+        return image.resize(gray, 84, 84, backend="pallas")
+
+    s = jax.ShapeDtypeStruct((1024,), jnp.float32, sharding=one_chip)
+    text = jax.jit(chain).lower(s, s, s, s).compile().as_text()
+    assert "convert" not in text
+    kernels = {}
+    for line in text.splitlines():
+        if KERNEL not in line:
+            continue
+        m = re.match(r"\s*(?:ROOT )?%(\w+)\.\d+ = (.*?) custom-call\(", line)
+        operands = re.search(
+            r"operand_layout_constraints=\{((?:[^{}]|\{[^{}]*\})*)\}", line)
+        assert m.group(1) not in kernels, m.group(1)
+        kernels[m.group(1)] = (
+            re.findall(r"(\w+)\[", m.group(2)),
+            re.findall(r"(\w+)\[1024,210,160\]", operands.group(1)),
+        )
+    # three kernels, under the names bench/metrics/image_kernels_roofline
+    # .train.py reads
+    assert set(kernels) == {"pong_render", "grayscale", "resize"}
+    for name, (results, images_in) in kernels.items():
+        assert results and set(results) == {"u8"}, (name, results)
+        assert set(images_in) <= {"u8"}, (name, images_in)
+    assert kernels["grayscale"][1] == ["u8"] * 3
+    assert kernels["resize"][1] == ["u8"]
