@@ -1,4 +1,4 @@
-"""Multi-device scale-out demo: ShardedDeviceEnvPool over a device mesh.
+"""Multi-device scale-out demo: the ``device-sharded`` engine over a mesh.
 
 The paper's headline numbers (1M FPS Atari, 3M FPS MuJoCo, §4.1) come
 from saturating all available hardware; here the same engine shards its

@@ -1,144 +1,13 @@
 #!/usr/bin/env bash
-# Tier-1 CI: full test suite + a multi-device throughput smoke.
+# Tier-1 CI: the full test suite on the CPU.  Speed is measured on a
+# chip only, with `python3 bench/run.py --workload <cell>`.
 #
-#   ./scripts/ci.sh            # everything
-#   CI_SKIP_BENCH=1 ./scripts/ci.sh   # tests only
+#   ./scripts/ci.sh
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
 
-# gate <banner> <bench args...> — one bench_throughput invocation per
-# gated mode; every floor rides on the args so the contract is visible
-# in one place at each call site.
-gate() {
-    echo "== $1 =="
-    shift
-    python benchmarks/bench_throughput.py "$@"
-}
-
 echo "== tier-1 tests =="
 python -m pytest -x -q
-
-if [ -z "${CI_SKIP_BENCH:-}" ]; then
-    gate "sharded-engine smoke (mesh=4, simulated host devices)" \
-        --mesh 4 --smoke
-
-    # regression gate for the batched-native env layer: the fused path
-    # must not fall behind the forced vmap-lifting baseline (0.7 floor
-    # absorbs 2-core CI timer noise; real regressions are step changes).
-    # Writes BENCH_throughput.json with the A/B numbers.
-    gate "batched-vs-vmap hot-path A/B smoke (Ant-v3)" \
-        --ab --smoke --min-ab-ratio 0.7
-
-    # the cost-aware schedulers must keep beating fifo on the long-tail
-    # skew workload (acceptance floor 1.15x; typical ≥ 2x — the 1.15
-    # margin absorbs CI timer noise).  Writes BENCH_schedule.json.
-    gate "scheduling-policy A/B smoke (fifo vs sjf/hierarchical, mesh=4)" \
-        --schedule --smoke --min-schedule-ratio 1.15
-
-    # the unified mesh engine's acceptance gate: the donated lax.scan
-    # collect (what rl/ppo.train_device runs — PoolState never leaves
-    # the mesh) must keep beating the per-step host-driven recv loop at
-    # mesh=4 (typical ≥ 5x on 2-core CI; the 1.2 floor is the
-    # regression gate).  Writes BENCH_resident.json.
-    gate "device-resident vs host-driven collect A/B (mesh 1 and 4)" \
-        --resident --smoke --min-resident-ratio 1.2
-
-    # the pipelined-driver gate: collect and update as two concurrently
-    # dispatched programs (rollout one policy step stale, V-trace
-    # corrected) must beat the fused-serial train_device program's
-    # wall-clock per update at mesh=4, where the fused path both
-    # serializes the phases and replicates the PPO epochs on every
-    # shard (typical ~2x on 1-core CI; 1.5 is the acceptance floor).
-    # Writes BENCH_pipelined.json (incl. both sides' mean_return for
-    # the reward-parity check).
-    gate "pipelined vs fused-serial collect/train A/B (mesh 1 and 4)" \
-        --pipelined --smoke --min-pipelined-ratio 1.5
-
-    echo "== transform-pipeline conformance (device/sharded mesh 1,2,4/thread) =="
-    # the in-engine pipeline's engine-conformance + golden-pin tests
-    # (also part of tier-1 above; re-run standalone so a bench-only CI
-    # invocation still exercises them)
-    python -m pytest -q tests/test_transforms.py
-
-    # EnvPool §3.4: preprocessing inside the engine must not lose to the
-    # gym-style wrapper placement (typical 3-4x in-engine at the smoke's
-    # N=64 on this 2-core CI; the 1.0 floor is the regression gate).
-    # Writes BENCH_transforms.json.
-    gate "in-engine vs python-wrapper preprocessing A/B (PongStack-v5)" \
-        --transforms --smoke --min-transform-ratio 1.0
-
-    echo "== image-kernel family conformance (Pallas gray/resize/crop/render) =="
-    # backend tri-identity (pallas-interpret == reference == jnp
-    # fallback, bitwise), the numpy mirrors, the PongClassic-v5 golden
-    # dynamics pin, and engine conformance (also tier-1; standalone for
-    # bench-only invocations)
-    python -m pytest -q tests/test_image_kernels.py
-
-    # the on-device image pipeline's acceptance gate: RGB render +
-    # grayscale/resize fused into the jitted recv must beat shipping
-    # raw 210x160x3 screens to a host-side numpy wrapper by >= 1.5x at
-    # the smoke's N=64 (typical ~1.8x on this CI).  Writes
-    # BENCH_image.json.
-    gate "in-engine vs python-wrapper IMAGE pipeline A/B (PongClassic-v5)" \
-        --image --smoke --min-image-ratio 1.5
-
-    echo "== LLM-policy decode-path parity (kernel/carriage/engine) =="
-    # ragged-length kernel parity, bitwise KV-cache carriage under
-    # top-M selection, and engine-served greedy streams vs the
-    # standalone Model.decode_step serving stack (also tier-1;
-    # standalone for bench-only invocations)
-    python -m pytest -q tests/test_decode_policy.py
-
-    # the decode-path acceptance gates: the cached one-token-per-recv
-    # decode_step must beat the full-recompute forward >= 3x per token
-    # at N=32 (typical larger — the baseline re-pays the whole prefix
-    # every token), and continuous batching must beat run-to-completion
-    # static batches >= 1.2x useful tokens/s on the ragged-length mix
-    # (typical ~2x at 75% short episodes).  Writes BENCH_decode.json.
-    gate "KV-cached decode + continuous-batching A/B (TokenCopy/TokenRagged)" \
-        --decode --smoke --min-decode-cached-ratio 3.0 \
-        --min-decode-cb-ratio 1.2
-
-    echo "== telemetry conformance (stats() on all six engines, mesh 1,2,4) =="
-    # the obs/ subsystem's engine-conformance + mesh-invariance tests
-    # (also tier-1 above; standalone for bench-only invocations)
-    python -m pytest -q tests/test_obs.py
-
-    # the instrumentation must stay in-graph integer noise: obs-on FPS
-    # >= 0.97x obs-off on the random-collect hot loop (acceptance bound
-    # is <= 3% overhead; typical parity on this CI — the counters are a
-    # handful of int32 adds against a full env step).  Writes
-    # BENCH_obs.json with both sides, the stats() snapshot, and the
-    # metrics-registry export.
-    gate "telemetry-overhead A/B gate (obs on vs off, device sync)" \
-        --obs --smoke --min-obs-ratio 0.97
-
-    echo "== multi-host loopback smoke (2 processes, gloo) =="
-    # process topology, bitwise 1-proc-vs-2-proc stream/stats
-    # invariance, and the compiled-HLO collective audit (fifo hot path
-    # = zero collectives; hierarchical+NormalizeObs = only the
-    # fixed-size cost all_gather + moment psums) — also tier-1 above;
-    # standalone for bench-only invocations
-    python -m pytest -q tests/test_multihost.py
-
-    # the multi-host acceptance gates.  The CONTRACT floors — 2-proc
-    # aggregate FPS >= 1.5x 1-proc weak scaling, disaggregated
-    # per-update >= 1.0x colocated — need at least two real cores: on a
-    # 1-core box both loopback ranks time-share one core, so the 2-proc
-    # sides measure multiplexing + broadcast overhead, not scaling.
-    # There the floors drop to regression tripwires (measured ~0.29
-    # weak / 0.18-0.31 disagg across runs on 1-core CI; an
-    # env-data-sized collective sneaking onto the hot path would
-    # crater them well below these).  Writes BENCH_multihost.json.
-    if [ "$(nproc)" -ge 2 ]; then
-        MH_FLOOR=1.5 DISAGG_FLOOR=1.0
-    else
-        MH_FLOOR=0.15 DISAGG_FLOOR=0.10
-    fi
-    gate "multi-host weak-scaling + disaggregation A/B (loopback)" \
-        --multihost --smoke --min-multihost-ratio "$MH_FLOOR" \
-        --min-disagg-ratio "$DISAGG_FLOOR"
-fi
 echo "CI OK"

@@ -2,7 +2,7 @@
 # re-built TPU-native in JAX (DESIGN.md §2) around two seams:
 #
 #   * ``core.protocol.EnvPool`` — ONE structural contract (specs +
-#     send/recv/step/sync reset) that all six engines satisfy; drivers
+#     send/recv/step/sync reset) that all five engines satisfy; drivers
 #     (``DmEnv``, ``build_collect_fn``, ``rl.ppo.train``) program
 #     against it, so the engine is an execution detail.  The device
 #     family additionally satisfies ``FunctionalEnvPool`` (pure state,
@@ -14,8 +14,7 @@
 #     env provides it, compiled on TPU with a bit-identical jnp
 #     reference fallback on CPU; a bitwise-equivalent vmap-lifting
 #     adapter everywhere else).
-from repro.core.device_pool import DeviceEnvPool, PoolState, make_pool
-from repro.core.engine import MeshEnvPool
+from repro.core.engine import MeshEnvPool, PoolState, make_env_mesh, make_pool
 from repro.core.protocol import (
     BoundEnvPool,
     EnvPool,
@@ -32,7 +31,6 @@ from repro.core.registry import (
     register,
     register_py,
 )
-from repro.core.sharded_pool import ShardedDeviceEnvPool, make_env_mesh
 from repro.core.specs import ArraySpec, EnvSpec, TimeStep
 from repro.core.transforms import (
     Crop,
@@ -53,7 +51,6 @@ __all__ = [
     "ArraySpec",
     "BoundEnvPool",
     "Crop",
-    "DeviceEnvPool",
     "DmEnv",
     "EnvPool",
     "EnvSpec",
@@ -69,7 +66,6 @@ __all__ = [
     "RewardClip",
     "Transform",
     "TransformPipeline",
-    "ShardedDeviceEnvPool",
     "TimeStep",
     "bind",
     "build_collect_fn",

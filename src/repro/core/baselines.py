@@ -77,12 +77,11 @@ class ForLoopEnv(_SyncSendRecv):
     """Paper Table 1 row 1: single-thread sequential stepping."""
 
     def __init__(self, env_fns: list[Callable[[], HostEnv]],
-                 transforms=(), obs: bool = True):
+                 transforms=()):
         self._envs = [fn() for fn in env_fns]
         self.num_envs = len(self._envs)
         self.batch_size = self.num_envs
-        self.obs = bool(obs)
-        self._tele = HostTelemetry(self.num_envs) if self.obs else None
+        self._tele = HostTelemetry(self.num_envs)
         # same transform pipeline as every other engine (numpy mirror),
         # applied to each assembled M == N block
         self._pipeline = TransformPipeline(transforms, self._envs[0].spec)
@@ -95,19 +94,16 @@ class ForLoopEnv(_SyncSendRecv):
         # pipeline state restarts with the envs (device init() parity)
         self._tf_state = self._pipeline.np_init(self.num_envs)
         out = _result_dict(self.num_envs, self.raw_spec.obs_spec)
-        if self._tele is not None:
-            self._tele.on_enqueue(out["env_id"], stepped=False)
+        self._tele.on_enqueue(out["env_id"], stepped=False)
         for i, e in enumerate(self._envs):
             out["obs"][i] = e.reset()
-        if self._tele is not None:
-            self._tele.record_block(out["env_id"], out["step_cost"])
+        self._tele.record_block(out["env_id"], out["step_cost"])
         self._tf_state, out = self._pipeline.np_apply(self._tf_state, out)
         return out
 
     def step(self, actions, env_ids=None) -> dict[str, np.ndarray]:
         out = _result_dict(self.num_envs, self.raw_spec.obs_spec)
-        if self._tele is not None:
-            self._tele.on_enqueue(out["env_id"], stepped=True)
+        self._tele.on_enqueue(out["env_id"], stepped=True)
         for i, e in enumerate(self._envs):
             obs, rew, done, info = e.step(actions[i])
             out["obs"][i] = obs
@@ -118,17 +114,12 @@ class ForLoopEnv(_SyncSendRecv):
             out["episode_return"][i] = info.get("episode_return", 0.0)
             out["episode_length"][i] = info.get("episode_length", 0)
             out["step_cost"][i] = info.get("step_cost", 1)
-        if self._tele is not None:
-            self._tele.record_block(out["env_id"], out["step_cost"])
+        self._tele.record_block(out["env_id"], out["step_cost"])
         self._tf_state, out = self._pipeline.np_apply(self._tf_state, out)
         return out
 
     def stats(self) -> dict:
         """Telemetry snapshot (core/protocol.py ``stats()`` contract)."""
-        if self._tele is None:
-            raise RuntimeError(
-                "telemetry disabled: pool was constructed with obs=False"
-            )
         return self._tele.snapshot()
 
     def close(self) -> None:
@@ -185,12 +176,10 @@ class SubprocessEnv(_SyncSendRecv):
         num_workers: int | None = None,
         spec=None,
         transforms=(),
-        obs: bool = True,
     ):
         self.num_envs = num_envs
         self.batch_size = num_envs
-        self.obs = bool(obs)
-        self._tele = HostTelemetry(num_envs) if self.obs else None
+        self._tele = HostTelemetry(num_envs)
         if spec is None:
             probe = env_factory(0)
             spec = probe.spec
@@ -270,9 +259,8 @@ class SubprocessEnv(_SyncSendRecv):
             self._recv_checked(c)
         out = _result_dict(self.num_envs, self.raw_spec.obs_spec)
         out["obs"][:] = self._obs  # batching copy (the paper counts this)
-        if self._tele is not None:
-            self._tele.on_enqueue(out["env_id"], stepped=False)
-            self._tele.record_block(out["env_id"], out["step_cost"])
+        self._tele.on_enqueue(out["env_id"], stepped=False)
+        self._tele.record_block(out["env_id"], out["step_cost"])
         self._tf_state, out = self._pipeline.np_apply(self._tf_state, out)
         return out
 
@@ -287,18 +275,13 @@ class SubprocessEnv(_SyncSendRecv):
             out["reward"][lo:hi] = rews
             out["done"][lo:hi] = dones
         out["obs"][:] = self._obs
-        if self._tele is not None:
-            self._tele.on_enqueue(out["env_id"], stepped=True)
-            self._tele.record_block(out["env_id"], out["step_cost"])
+        self._tele.on_enqueue(out["env_id"], stepped=True)
+        self._tele.record_block(out["env_id"], out["step_cost"])
         self._tf_state, out = self._pipeline.np_apply(self._tf_state, out)
         return out
 
     def stats(self) -> dict:
         """Telemetry snapshot (core/protocol.py ``stats()`` contract)."""
-        if self._tele is None:
-            raise RuntimeError(
-                "telemetry disabled: pool was constructed with obs=False"
-            )
         return self._tele.snapshot()
 
     def close(self) -> None:

@@ -7,9 +7,9 @@ is that component for the JAX engines, written exactly once:
 
   * every piece of engine logic — scheduler aliasing
     (``core/scheduler.py``), transform application
-    (``core/transforms.py``), ``_serve`` / ``_tick`` / ``_recv_topm`` /
-    ``_recv_masked``, init-from-keys — is a **per-shard pure function**
-    over a local ``PoolState`` block;
+    (``core/transforms.py``), ``_serve`` / ``_recv_topm``,
+    init-from-keys — is a **per-shard pure function** over a local
+    ``PoolState`` block;
   * the public ``init``/``send``/``recv`` wrap those bodies in ONE
     ``shard_map`` over a 1-D device mesh.  ``engine="device"`` is simply
     the degenerate ``num_shards=1`` mesh; ``engine="device-sharded"``
@@ -41,10 +41,9 @@ trainer that stacks sync blocks over time (GAE, ``rl/ppo.py``) reads
 each lane's own stream, and sync streams are bitwise-identical at all
 mesh sizes (tests/test_sharded_pool.py, tests/_sharded_check.py).
 
-Three execution modes (identical to the classic engine):
-  * ``sync``   — step all N each recv (gym.vector semantics, M = N).
-  * ``async``  — top-M selection under the pool's ``schedule=`` policy.
-  * ``masked`` — event-driven tick ablation (literal EnvPool semantics).
+Two execution modes, one recv path (``_recv_topm``):
+  * ``sync``  — step all N each recv (gym.vector semantics, M = N).
+  * ``async`` — top-M selection under the pool's ``schedule=`` policy.
 
 All public methods are pure functions over ``PoolState`` → the whole
 pool is jittable, scannable and donate-able inside ``lax.scan`` (paper
@@ -77,7 +76,6 @@ from repro.envs.base import Environment
 from repro.envs.batch import as_batch_env
 from repro.obs.telemetry import (
     init_telemetry,
-    record_finished,
     record_serve,
     snapshot_device,
     telemetry_local,
@@ -88,7 +86,6 @@ from repro.obs.trace import (
     GATHER,
     SEND,
     TELEMETRY,
-    TICK,
     TRANSFORM,
     WRITEBACK,
 )
@@ -148,7 +145,6 @@ class LaneRows:
 
     env_states: Any            # the env's state pytree
     actions: jnp.ndarray       # (*act_shape) action table
-    progress: jnp.ndarray      # int32 substeps done (masked mode)
     # stored results for READY envs (obs always re-derived from env state)
     r_reward: jnp.ndarray
     r_done: jnp.ndarray
@@ -185,13 +181,11 @@ class PoolState:
     # bitwise-unchanged.
     tf_state: Any = ()
     # in-graph engine counters (obs/telemetry.py): a ``Telemetry``
-    # pytree updated inside the jitted recv/tick bodies — per-lane
-    # leaves carry the N dim, per-shard partial sums the (D,) dim —
-    # and read on the host only by an explicit ``pool.stats()``
-    # snapshot.  Counters never feed back into env math, scheduling,
-    # or RNG, so served streams stay bitwise-unchanged; ``obs=False``
-    # makes this the empty tuple (zero leaves — the exact pre-
-    # telemetry program, the ``bench_throughput --obs`` baseline).
+    # pytree updated inside the jitted recv body — per-lane leaves
+    # carry the N dim, per-shard partial sums the (D,) dim — and read
+    # on the host only by an explicit ``pool.stats()`` snapshot.
+    # Counters never feed back into env math, scheduling, or RNG, so
+    # served streams stay bitwise-unchanged.
     telemetry: Any = ()
 
 
@@ -220,7 +214,6 @@ class MeshEnvPool:
         schedule: str | Scheduler = "fifo",
         sched_patience: float = 1.0,
         transforms: Any = (),
-        obs: bool = True,
     ):
         if batch_size is None:
             batch_size = num_envs
@@ -228,7 +221,7 @@ class MeshEnvPool:
             mode = "sync" if batch_size == num_envs else "async"
         if batch_size > num_envs:
             raise ValueError("batch_size cannot exceed num_envs (paper §3.2)")
-        if mode not in ("sync", "async", "masked"):
+        if mode not in ("sync", "async"):
             raise ValueError(f"unknown mode {mode!r}")
         if mode == "sync" and batch_size != num_envs:
             raise ValueError("sync mode requires batch_size == num_envs")
@@ -250,12 +243,6 @@ class MeshEnvPool:
         self.num_envs = int(num_envs)
         self.batch_size = int(batch_size)
         self.mode = mode
-        # in-graph telemetry (obs/telemetry.py): counters ride on
-        # PoolState and update inside the jitted recv bodies.  They
-        # never feed env math/scheduling/RNG, so served streams are
-        # bitwise-unchanged; obs=False drops every telemetry leaf —
-        # the exact pre-telemetry XLA program (the bench baseline).
-        self.obs = bool(obs)
         self._n_local = self.num_envs // d
         self._m_local = self.batch_size // d
         # selection policy (core/scheduler.py): which M/D lanes each
@@ -281,11 +268,11 @@ class MeshEnvPool:
             transforms, env.spec, axis_name=axis_name if d > 1 else None
         )
         self.raw_spec = env.spec
-        # THE hot-path engine: a batched-native view of the env.  All
-        # recv/tick bodies drive batched primitives (one fused
-        # multi-substep call per shard per recv) — never per-lane
-        # ``env.step`` under vmap.  ``batched=False`` forces the generic
-        # vmap-lifting adapter (the A/B baseline).
+        # THE hot-path engine: a batched-native view of the env.  The
+        # recv body drives batched primitives (one fused multi-substep
+        # call per shard per recv) — never per-lane ``env.step`` under
+        # vmap.  ``batched=False`` forces the generic vmap-lifting
+        # adapter (the conformance reference).
         self.benv = as_batch_env(env, native=batched)
         # drivers see the TRANSFORMED spec; act_spec is never changed
         self.spec = self.pipeline.out_spec
@@ -319,8 +306,7 @@ class MeshEnvPool:
         return ps.replace(
             tick=ps.tick[0], rng=ps.rng[0],
             tf_state=self._tf_local(ps.tf_state),
-            telemetry=telemetry_local(ps.telemetry)
-            if self.obs else ps.telemetry,
+            telemetry=telemetry_local(ps.telemetry),
         )
 
     def _shard_view(self, ps: PoolState) -> PoolState:
@@ -328,8 +314,7 @@ class MeshEnvPool:
         return ps.replace(
             tick=ps.tick[None], rng=ps.rng[None],
             tf_state=self._tf_shard(ps.tf_state),
-            telemetry=telemetry_shard(ps.telemetry)
-            if self.obs else ps.telemetry,
+            telemetry=telemetry_shard(ps.telemetry),
         )
 
     def _smap(self, f, n_in: int, n_out: int = 1):
@@ -349,7 +334,6 @@ class MeshEnvPool:
         return LaneRows(
             env_states=self.benv.v_init_state(env_keys),
             actions=jnp.zeros((n,) + act.shape, act.dtype),
-            progress=jnp.zeros((n,), jnp.int32),
             r_reward=jnp.zeros((n,), jnp.float32),
             r_done=jnp.zeros((n,), jnp.bool_),
             r_term=jnp.zeros((n,), jnp.bool_),
@@ -371,7 +355,7 @@ class MeshEnvPool:
             tick=jnp.int32(0),
             rng=rng,
             tf_state=self.pipeline.init(n),
-            telemetry=init_telemetry(n) if self.obs else (),
+            telemetry=init_telemetry(n),
         )
 
     def _init_from_keys_impl(self, env_keys: jax.Array, rng: jax.Array
@@ -416,13 +400,9 @@ class MeshEnvPool:
         costs = self.benv.v_step_cost(sel.env_states, actions)
         costs = jnp.clip(costs, self.spec.min_cost, self.spec.max_cost)
         ss = self.scheduler.enqueue(self._sched_view(ps), local_ids, costs)
-        # the gathered rows with the new action and progress: one row
-        # scatter writes both
-        lanes = self.lanes.scatter(
-            ps.lanes, local_ids,
-            sel.replace(actions=actions,
-                        progress=jnp.zeros(local_ids.shape, jnp.int32)),
-        )
+        # the gathered rows with the new action: one row scatter
+        lanes = self.lanes.scatter(ps.lanes, local_ids,
+                                   sel.replace(actions=actions))
         return ps.replace(
             lanes=lanes,
             phase=ss.phase,
@@ -459,9 +439,8 @@ class MeshEnvPool:
         """Run the transform pipeline over one served (raw) block —
         inside the per-shard recv body, so the preprocessing fuses into
         the same XLA program as the recv itself.  Applied exactly once
-        per served result (both recv flavors serve through here);
-        per-lane transform state rows are gathered for the block and
-        scattered back onto ``PoolState``."""
+        per served result; per-lane transform state rows are gathered
+        for the block and scattered back onto ``PoolState``."""
         if not self.pipeline:
             return ps, out
         with jax.named_scope(TRANSFORM):
@@ -472,19 +451,16 @@ class MeshEnvPool:
 
     def _recv_topm(self, ps: PoolState) -> tuple[PoolState, TimeStep]:
         full_block = self._m_local == ps.phase.shape[0]
-        if self.obs:
-            idx, overdue = self.scheduler.select_info(
-                self._sched_view(ps), self._m_local
-            )
-            # queue-wait (recv ticks since the action was enqueued),
-            # read BEFORE ``complete`` advances the tick.  A full-size
-            # block serves every lane, so wait stays in lane order and
-            # record_serve takes its scatter-free fast path.
-            with jax.named_scope(TELEMETRY):
-                wait = (ps.tick - ps.send_tick if full_block
-                        else ps.tick - ps.send_tick[idx])
-        else:
-            idx = self.scheduler.select(self._sched_view(ps), self._m_local)
+        idx, overdue = self.scheduler.select_info(
+            self._sched_view(ps), self._m_local
+        )
+        # queue-wait (recv ticks since the action was enqueued), read
+        # BEFORE ``complete`` advances the tick.  A full-size block
+        # serves every lane, so wait stays in lane order and
+        # record_serve takes its scatter-free fast path.
+        with jax.named_scope(TELEMETRY):
+            wait = (ps.tick - ps.send_tick if full_block
+                    else ps.tick - ps.send_tick[idx])
 
         with jax.named_scope(GATHER):
             sel = self.lanes.gather(ps.lanes, idx)
@@ -535,142 +511,20 @@ class MeshEnvPool:
                 ))
             ss = self.scheduler.complete(self._sched_view(ps), idx)
             ps = ps.replace(lanes=lanes, phase=ss.phase, tick=ss.tick)
-        if self.obs:
-            ps = ps.replace(
-                telemetry=record_serve(
-                    ps.telemetry, idx, wait, need_step,
-                    out.step_cost, overdue, full_block=full_block,
-                )
+        ps = ps.replace(
+            telemetry=record_serve(
+                ps.telemetry, idx, wait, need_step,
+                out.step_cost, overdue, full_block=full_block,
             )
+        )
         # stored r_* results stay RAW; the pipeline runs at serve time
-        # (masked mode serves stored results through the same path, so
-        # both recv flavors emit identical transformed streams)
         return self._serve(ps, idx, out)
-
-    # ------------------------------------------------------------------ #
-    # masked (event-driven tick) mode — the literal-semantics ablation
-    # ------------------------------------------------------------------ #
-    @jax.named_scope(TICK)
-    def _tick(self, ps: PoolState, rows: LaneRows
-              ) -> tuple[PoolState, LaneRows]:
-        """Advance every HAS_ACTION lane one substep (idle lanes masked),
-        over the unpacked lane rows."""
-        busy = ps.phase == HAS_ACTION
-        starting = busy & (rows.progress == 0)
-        # clear accumulators at the start of a step
-        pre = self.benv.v_pre_step(rows.env_states)
-        states = jax.tree.map(
-            lambda p, s: jnp.where(
-                starting.reshape(starting.shape + (1,) * (p.ndim - 1)), p, s
-            ),
-            pre,
-            rows.env_states,
-        )
-        stepped = self.benv.v_substep(states, rows.actions)
-        running = busy & (rows.progress < ps.cost)
-        states = jax.tree.map(
-            lambda n, o: jnp.where(
-                running.reshape(running.shape + (1,) * (n.ndim - 1)), n, o
-            ),
-            stepped,
-            states,
-        )
-        progress = jnp.where(running, rows.progress + 1, rows.progress)
-        finished = busy & (progress >= ps.cost)
-
-        fin_states, fin_ts = self.benv.v_finalize(states, ps.cost)
-        states = jax.tree.map(
-            lambda f, s: jnp.where(
-                finished.reshape(finished.shape + (1,) * (f.ndim - 1)), f, s
-            ),
-            fin_states,
-            states,
-        )
-        rows = rows.replace(
-            env_states=states,
-            progress=progress,
-            r_reward=jnp.where(finished, fin_ts.reward, rows.r_reward),
-            r_done=jnp.where(finished, fin_ts.done, rows.r_done),
-            r_term=jnp.where(finished, fin_ts.terminated, rows.r_term),
-            r_trunc=jnp.where(finished, fin_ts.truncated, rows.r_trunc),
-            r_ep_return=jnp.where(finished, fin_ts.episode_return,
-                                  rows.r_ep_return),
-            r_ep_length=jnp.where(finished, fin_ts.episode_length,
-                                  rows.r_ep_length),
-            r_cost=jnp.where(finished, ps.cost, rows.r_cost),
-        )
-        new = ps.replace(
-            phase=jnp.where(finished, READY, ps.phase),
-            send_tick=jnp.where(finished, ps.tick, ps.send_tick),
-        )
-        if self.obs:
-            # substep accounting belongs to the tick that finished the
-            # work; the serve is recorded later with stepped_mask=False
-            new = new.replace(
-                telemetry=record_finished(ps.telemetry, finished, ps.cost)
-            )
-        return new, rows
-
-    def _recv_masked(self, ps: PoolState) -> tuple[PoolState, TimeStep]:
-        m = self._m_local
-
-        def not_enough(carry):
-            return jnp.sum(carry[0].phase == READY) < m
-
-        with jax.named_scope(TICK):
-            # the loop body keeps dense per-leaf arithmetic: unpack the
-            # slab once before the loop and pack it once after
-            rows = self.lanes.unpack(ps.lanes)
-            ps, rows = lax.while_loop(not_enough, lambda c: self._tick(*c),
-                                      (ps, rows))
-            ps = ps.replace(lanes=self.lanes.pack(rows))
-        # completion order ≈ send_tick order among READY (policy-
-        # independent by the select_ready contract)
-        idx = self.scheduler.select_ready(self._sched_view(ps), m)
-        with jax.named_scope(GATHER):
-            sel = self.lanes.gather(ps.lanes, idx)
-        with jax.named_scope(ENV_STEP):
-            obs = self.benv.v_observe(sel.env_states)
-        out = TimeStep(
-            obs=obs,
-            reward=sel.r_reward,
-            done=sel.r_done,
-            terminated=sel.r_term,
-            truncated=sel.r_trunc,
-            env_id=idx,
-            episode_return=sel.r_ep_return,
-            episode_length=sel.r_ep_length,
-            step_cost=sel.r_cost,
-        )
-        ss = self.scheduler.complete(self._sched_view(ps), idx)
-        if self.obs:
-            # wait since the step COMPLETED (``_tick`` stamps send_tick
-            # at finish); substeps were already counted per-tick, so
-            # the serve records with stepped_mask=False
-            with jax.named_scope(TELEMETRY):
-                wait = ps.tick - ps.send_tick[idx]
-        ps = ps.replace(phase=ss.phase, tick=ss.tick)
-        if self.obs:
-            ps = ps.replace(
-                telemetry=record_serve(
-                    ps.telemetry, idx, wait,
-                    jnp.zeros(idx.shape, jnp.bool_),
-                    jnp.zeros(idx.shape, jnp.int32),
-                    jnp.int32(0),
-                )
-            )
-        return self._serve(ps, idx, out)
-
-    def _local_recv(self, ps: PoolState) -> tuple[PoolState, TimeStep]:
-        if self.mode == "masked":
-            return self._recv_masked(ps)
-        return self._recv_topm(ps)
 
     def _recv_impl(self, ps: PoolState) -> tuple[PoolState, TimeStep]:
         n_local = self._n_local
 
         def recv_shard(ps_s):
-            local, ts = self._local_recv(self._local_view(ps_s))
+            local, ts = self._recv_topm(self._local_view(ps_s))
             shard = lax.axis_index(self.axis_name).astype(jnp.int32)
             ts = ts.replace(env_id=ts.env_id + shard * n_local)
             if self.mode == "sync":
@@ -754,10 +608,6 @@ class MeshEnvPool:
         telemetry crosses to the host.  Per-shard partial sums are summed
         over D (integer adds: bitwise mesh-size-invariant); ``recvs``
         comes from the replicated tick, shard 0's copy."""
-        if not self.obs:
-            raise RuntimeError(
-                "telemetry disabled: pool was constructed with obs=False"
-            )
         with jax.profiler.TraceAnnotation("pool.stats"):
             tel, tick = ps.telemetry, ps.tick
             if self.is_multiprocess:
@@ -885,13 +735,12 @@ def make_pool(
     batched: bool | None = None,
     schedule: str | Scheduler = "fifo",
     transforms: Any = (),
-    obs: bool = True,
 ) -> MeshEnvPool:
     """EnvPool constructor with the paper's mode convention: sync iff
     batch_size in (None, num_envs) — which is exactly the engine's own
     ``mode=None`` default."""
     return MeshEnvPool(env, num_envs, batch_size, mode=mode, batched=batched,
-                       schedule=schedule, transforms=transforms, obs=obs)
+                       schedule=schedule, transforms=transforms)
 
 
 __all__ = [

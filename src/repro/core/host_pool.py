@@ -144,7 +144,6 @@ class ThreadEnvPool:
         aging: float = 1.0,
         cost_ema_alpha: float = 1.0,
         transforms: Any = (),
-        obs: bool = True,
     ):
         self.num_envs = len(env_fns)
         self.batch_size = batch_size or self.num_envs
@@ -181,8 +180,7 @@ class ThreadEnvPool:
         # numpy mirror of the device engines' in-graph counters
         # (obs/telemetry.py): the pool tags what it enqueues and counts
         # what it serves, so ``stats()`` is engine-conformant
-        self.obs = bool(obs)
-        self._tele = HostTelemetry(self.num_envs) if self.obs else None
+        self._tele = HostTelemetry(self.num_envs)
 
         self._envs = [fn() for fn in env_fns]
         # host side of the in-engine pipeline (core/transforms.py): the
@@ -294,13 +292,11 @@ class ThreadEnvPool:
         # tf_state) — without this a second reset would serve frame
         # stacks still holding pre-reset frames
         self._tf_state = self._pipeline.np_init(self.num_envs)
-        if self._tele is not None:
-            self._tele.on_enqueue(np.arange(self.num_envs), stepped=False)
+        self._tele.on_enqueue(np.arange(self.num_envs), stepped=False)
         self._actions.put_batch([(i, _RESET) for i in range(self.num_envs)])
 
     def send(self, actions: np.ndarray, env_ids: np.ndarray) -> None:
-        if self._tele is not None:
-            self._tele.on_enqueue(np.asarray(env_ids), stepped=True)
+        self._tele.on_enqueue(np.asarray(env_ids), stepped=True)
         items = [(int(e), a) for e, a in zip(env_ids, actions)]
         if self.schedule != "fifo":
             ids = np.asarray(env_ids, np.int64)
@@ -346,8 +342,7 @@ class ThreadEnvPool:
         # EMA of observed cost (alpha=1.0 -> last-observed, bitwise the
         # classic estimator)
         ids = out["env_id"]
-        if self._tele is not None:
-            self._tele.record_block(ids, out["step_cost"])
+        self._tele.record_block(ids, out["step_cost"])
         observed = np.maximum(out["step_cost"], 1).astype(np.float32)
         a = self.cost_ema_alpha
         self._est_cost[ids] = a * observed + (1.0 - a) * self._est_cost[ids]
@@ -379,10 +374,6 @@ class ThreadEnvPool:
     def stats(self) -> dict:
         """Telemetry snapshot (core/protocol.py ``stats()`` contract) —
         same keys and semantics as the device engines'."""
-        if self._tele is None:
-            raise RuntimeError(
-                "telemetry disabled: pool was constructed with obs=False"
-            )
         return self._tele.snapshot()
 
     def close(self) -> None:

@@ -1,7 +1,7 @@
 """The ``EnvPool`` protocol — ONE spec-driven front-end for all engines.
 
-Every engine (`device`, `device-masked`, `device-sharded`, `thread`,
-`forloop`, `subprocess`) satisfies the same structural contract: specs
+Every engine (`device`, `device-sharded`, `thread`, `forloop`,
+`subprocess`) satisfies the same structural contract: specs
 (``spec``/``num_envs``/``batch_size``) plus the paper's §3.1 API
 (``send``/``recv``/``step``/sync ``reset``).  Drivers — the dm_env
 facade, the XLA collect loop, PPO — program against this protocol, so
@@ -15,12 +15,12 @@ Two calling conventions exist underneath:
   TimeStep)`` — jittable, scannable, donate-able (paper Appendix E).
   There is ONE functional engine class (``MeshEnvPool``): its bodies
   are per-shard pure functions wrapped in ``shard_map`` over a 1-D
-  device mesh, and ``device`` / ``device-masked`` / ``device-sharded``
-  differ only in the mesh (``device`` is the degenerate 1-shard mesh)
-  and execution mode.  ``PoolState`` stays sharded over the mesh for
-  the life of the pool — drivers scan over it without ever pulling it
-  to the host, and ``state_shardings``/``device_put`` expose the
-  layout (``distributed/sharding.py`` rules) for long-lived carries.
+  device mesh, and ``device`` / ``device-sharded`` differ only in the
+  mesh (``device`` is the degenerate 1-shard mesh).  ``PoolState``
+  stays sharded over the mesh for the life of the pool — drivers scan
+  over it without ever pulling it to the host, and
+  ``state_shardings``/``device_put`` expose the layout
+  (``distributed/sharding.py`` rules) for long-lived carries.
 * **host** engines (thread / forloop / subprocess): stateful objects —
   ``send(actions, ids)``, ``recv() -> dict``, ``reset() -> dict``.
 
@@ -98,7 +98,7 @@ cost sums, scheduler overdue-band admissions).  On the functional
 engines the counters are a ``Telemetry`` pytree riding on ``PoolState``
 (the ``tf_state`` carriage pattern: per-lane ``(N,)`` leaves partition
 with the env states, per-shard partial sums carry the ``(D,)`` dim),
-updated INSIDE the jitted recv/tick bodies as fixed-size integer ops
+updated INSIDE the jitted recv body as fixed-size integer ops
 and crossing to the host only at the explicit ``stats(ps)`` call —
 never on the hot path, never via collectives (integer partial sums
 are summed on the host, so snapshots are bitwise mesh-size-invariant
@@ -107,10 +107,7 @@ at every D).  Host engines mirror the same counters in numpy
 engine-conformant: the same scripted rollout yields the same counter
 values on every engine (tests/test_obs.py).  Like transform and lane
 state, telemetry never feeds back into env math, scheduling, or RNG —
-served streams (and goldens) are bitwise-unchanged with it on, and
-``obs=False`` at construction strips every counter leaf, recovering
-the exact uninstrumented program (``stats()`` then raises
-``RuntimeError``).
+served streams (and goldens) are bitwise-unchanged with it on.
 
 Multi-host contract (``launch/mesh.py`` + ``distributed/sharding.py``):
 after ``initialize_multihost(coordinator, num_processes, process_id)``

@@ -18,9 +18,8 @@ One spec-driven front-end constructs every engine:
 
   engine            pool class              execution substrate
   ----------------  ----------------------  ---------------------------------
-  device (default)  DeviceEnvPool           vmap lanes, one device
-  device-masked     DeviceEnvPool(masked)   tick ablation, one device
-  device-sharded    ShardedDeviceEnvPool    shard_map over a device mesh
+  device (default)  MeshEnvPool             one-device mesh
+  device-sharded    MeshEnvPool             shard_map over a device mesh
   thread            ThreadEnvPool           host threads (paper's C++ pool)
   forloop           ForLoopEnv              sequential baseline (Table 1)
   subprocess        SubprocessEnv           gym.vector-style workers
@@ -38,7 +37,7 @@ from typing import Any, Callable
 
 import numpy as np
 
-from repro.core.device_pool import DeviceEnvPool
+from repro.core.engine import MeshEnvPool, derive_env_keys, make_env_mesh
 from repro.core.transforms import Transform, resolve_transforms
 from repro.envs.base import Environment
 
@@ -49,10 +48,7 @@ _PY_REGISTRY: dict[str, Callable[..., Any]] = {}
 _TRANSFORMS: dict[str, tuple[Transform, ...]] = {}
 _DEFAULTS_DONE = False
 
-ENGINES = (
-    "device", "device-masked", "device-sharded",
-    "thread", "forloop", "subprocess",
-)
+ENGINES = ("device", "device-sharded", "thread", "forloop", "subprocess")
 
 
 def register(name: str, factory: Callable[..., Environment],
@@ -91,10 +87,8 @@ def _jax_env(task_id: str, **kwargs: Any) -> Environment:
 
 
 def _host_env_keys(seed: int, num_envs: int) -> np.ndarray:
-    """Per-env init keys matching ``DeviceEnvPool.init(PRNGKey(seed))``."""
+    """Per-env init keys matching ``MeshEnvPool.init(PRNGKey(seed))``."""
     import jax
-
-    from repro.core.device_pool import derive_env_keys
 
     keys, _ = derive_env_keys(jax.random.PRNGKey(seed), num_envs)
     return np.asarray(keys)
@@ -114,7 +108,6 @@ def make(
     sched_patience: float = 1.0,
     cost_ema_alpha: float = 1.0,
     transforms: Any = None,
-    obs: bool = True,
     **env_kwargs: Any,
 ):
     """Create a vectorized env pool, EnvPool-style.
@@ -123,7 +116,7 @@ def make(
     device family, ``batched`` selects the batched-env implementation:
     None (default) lets the env pick its native one (e.g. the Pallas
     ``env_step`` kernel for MujocoLike), False forces the generic
-    vmap-lifting adapter (the A/B baseline).
+    vmap-lifting adapter (the conformance reference).
 
     ``schedule`` picks the async selection policy (``core/scheduler.py``:
     ``"fifo"`` — the default, preserving the classic engine behavior —
@@ -143,41 +136,29 @@ def make(
     the raw env stream.  ``pool.spec`` always reflects the transformed
     observation layout.
 
-    ``obs`` (default True) enables engine telemetry: the in-graph
-    counters on the device family, the numpy mirror on the host
-    engines — surfaced by ``pool.stats()`` (``obs/telemetry.py``).
-    ``obs=False`` strips every counter for an instrumentation-free
-    pool (the ``bench_throughput --obs`` baseline).
+    Every engine counts its own work (``obs/telemetry.py``): the
+    in-graph counters on the device family, the numpy mirror on the
+    host engines — surfaced by ``pool.stats()``.
     """
     _ensure_defaults()
     tfs = resolve_transforms(transforms, _TRANSFORMS.get(task_id, ()))
-    if engine in ("device", "device-masked"):
-        if schedule == "hierarchical":
-            # the cross-shard policy only makes sense with a real mesh;
-            # the degenerate single-device engine keeps rejecting it
-            raise ValueError(
-                "schedule='hierarchical' is the cross-shard policy: it "
-                "needs a device mesh (use engine='device-sharded')"
-            )
-        env = _jax_env(task_id, **env_kwargs)
-        mode = None if engine == "device" else "masked"
-        if mode is None:
-            mode = "sync" if batch_size in (None, num_envs) else "async"
-        return DeviceEnvPool(env, num_envs, batch_size, mode=mode,
-                             batched=batched, schedule=schedule,
-                             sched_patience=sched_patience, transforms=tfs,
-                             obs=obs)
-
-    if engine == "device-sharded":
-        from repro.core.sharded_pool import ShardedDeviceEnvPool
-
-        env = _jax_env(task_id, **env_kwargs)
-        return ShardedDeviceEnvPool(
-            env, num_envs, batch_size,
-            mesh=mesh if mesh is not None else num_shards,
-            batched=batched, schedule=schedule,
-            sched_patience=sched_patience, transforms=tfs, obs=obs,
-        )
+    if engine in ("device", "device-sharded"):
+        if engine == "device":
+            if schedule == "hierarchical":
+                # the cross-shard policy only makes sense with a real
+                # mesh; the degenerate single-device engine rejects it
+                raise ValueError(
+                    "schedule='hierarchical' is the cross-shard policy: "
+                    "it needs a device mesh (use engine='device-sharded')"
+                )
+            mesh = None
+        elif mesh is None:
+            # all devices unless ``num_shards`` names fewer
+            mesh = make_env_mesh(num_shards)
+        return MeshEnvPool(_jax_env(task_id, **env_kwargs), num_envs,
+                           batch_size, mesh=mesh, batched=batched,
+                           schedule=schedule, sched_patience=sched_patience,
+                           transforms=tfs)
 
     if engine == "thread":
         from repro.core.host_pool import JittedHostEnv, ThreadEnvPool
@@ -192,8 +173,7 @@ def make(
         ]
         return ThreadEnvPool(fns, batch_size=batch_size,
                              num_threads=num_threads, schedule=schedule,
-                             cost_ema_alpha=cost_ema_alpha, transforms=tfs,
-                             obs=obs)
+                             cost_ema_alpha=cost_ema_alpha, transforms=tfs)
 
     if engine in ("forloop", "subprocess") and schedule != "fifo":
         raise ValueError(
@@ -213,7 +193,7 @@ def make(
             ))
             for i in range(num_envs)
         ]
-        return ForLoopEnv(fns, transforms=tfs, obs=obs)
+        return ForLoopEnv(fns, transforms=tfs)
 
     if engine == "subprocess":
         from repro.core.baselines import SubprocessEnv
@@ -226,7 +206,6 @@ def make(
             num_workers=num_threads,
             spec=env.spec,
             transforms=tfs,
-            obs=obs,
         )
 
     raise ValueError(f"unknown engine {engine!r}; known: {ENGINES}")
@@ -313,13 +292,13 @@ def _ensure_defaults() -> None:
                          RewardClip()))
     register("AntNorm-v3", MujocoLike, transforms=(NormalizeObs(),))
     # long-tail-skew workloads (heterogeneous per-episode step cost —
-    # the scheduling-policy benchmark; see bench_throughput --schedule)
+    # the scheduling-policy workload)
     register(
         "TokenSkew-v0",
         lambda **kw: TokenEnv(**{"heavy_frac": 0.25, "heavy_scale": 8, **kw}),
     )
     # ragged GENERATION lengths (75% of episodes end at ep_len/4): the
-    # continuous-batching serving mix — see bench_throughput --decode
+    # continuous-batching serving mix
     register(
         "TokenRagged-v0",
         lambda **kw: TokenEnv(**{"short_frac": 0.75, "len_scale": 4, **kw}),

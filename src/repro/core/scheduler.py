@@ -3,10 +3,8 @@
 EnvPool's async mode (``batch_size M < num_envs N``) is a scheduling
 problem: each ``recv`` must pick the M lanes to serve next, and under
 heterogeneous step cost the pick *is* a first-order throughput lever
-(Sample Factory's lesson).  Before this module the policy was
-triplicated — ``DeviceEnvPool._priority``, a per-shard copy inside
-``ShardedDeviceEnvPool.recv``, and the ad-hoc host queue order in
-``ThreadEnvPool``.  Now every engine consumes one functional contract:
+(Sample Factory's lesson).  Every engine consumes one functional
+contract:
 
   * ``SchedState`` — a pytree of the per-lane scheduling signals
     (phase / predicted cost / enqueue tick / global tick).  The device
@@ -14,8 +12,6 @@ triplicated — ``DeviceEnvPool._priority``, a per-shard copy inside
     engine mirrors it in numpy.
   * ``enqueue(ss, lane_ids, costs)`` — lanes received an action.
   * ``select(ss, m)`` — the M lanes to serve this recv (policy-defined).
-  * ``select_ready(ss, m)`` — completion-order pick among READY lanes
-    (the masked/tick engine's recv; policy-independent by contract).
   * ``complete(ss, idx)`` — served lanes go back to WAITING, tick += 1.
 
 Policies
@@ -169,19 +165,6 @@ class Scheduler:
         ``idx`` is exactly ``select``'s — the engine can call this
         unconditionally without perturbing fifo/sjf selections."""
         return self.select(ss, m), jnp.int32(0)
-
-    @jax.named_scope(SELECT)
-    def select_ready(self, ss: SchedState, m: int) -> jnp.ndarray:
-        """Completion-order pick among READY lanes only — the masked
-        (event-driven tick) engine's recv, where results materialize by
-        themselves and scheduling freedom is which finished results to
-        hand out first.  Policy-independent by contract: completion
-        order ≈ enqueue order, exactly the StateBufferQueue."""
-        prio = jnp.where(
-            ss.phase == READY, ss.send_tick.astype(jnp.float32), _BIG
-        )
-        _, idx = lax.top_k(-prio, m)
-        return idx.astype(jnp.int32)
 
     @jax.named_scope(SELECT)
     def complete(self, ss: SchedState, idx: jnp.ndarray) -> SchedState:

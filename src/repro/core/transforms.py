@@ -50,8 +50,7 @@ same safety-contract style as ``core/scheduler.py``:
 
 The pipeline applies exactly once per served result, in list order, to
 the *raw* merged block (device engines store raw results and transform
-at serve time, so the masked/tick engine and the top-M engine emit the
-same transformed streams).  The policy-visible consequence: transforms
+at serve time).  The policy-visible consequence: transforms
 never change per-env trajectories (reward/done as produced by the env,
 engine scheduling, auto-reset points) — only the *served view* of them.
 

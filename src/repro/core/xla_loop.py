@@ -12,13 +12,7 @@ XLA reuse the SoA env buffers in place.
 jitted ``lax.scan`` body; host engines (thread / forloop / subprocess)
 get a numpy driver with the SAME signature and the same stacked
 ``(num_steps, batch, ...)`` trajectory layout, so benchmarks and
-training code run unchanged across all six engines.
-
-``build_stepwise_collect_fn`` is the ablation of the scan: one jitted
-``step`` dispatch per env step with the batch materialized on the host
-every step (the classic Appendix-E handle loop WITHOUT the scan).  It
-exists as the baseline for ``bench_throughput.py --resident``, which
-gates that the device-resident scan keeps beating it.
+training code run unchanged across all five engines.
 
 ``build_pipelined_collect_fn`` is the double-buffer sibling: the same
 donated scan, but returning a flat *rollout dict* (obs / actions /
@@ -40,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.core.device_pool import DeviceEnvPool, PoolState
+from repro.core.engine import PoolState
 from repro.core.protocol import EnvPool, is_functional, to_timestep
 from repro.core.specs import TimeStep
 from repro.obs.trace import COLLECT, POLICY
@@ -113,40 +107,6 @@ def build_collect_fn(
         return None, ts, traj, jnp.stack(acts)
 
     return collect_host
-
-
-def build_stepwise_collect_fn(
-    pool: EnvPool,
-    policy_fn: Callable[[Any, Any, jax.Array], Any],
-    num_steps: int,
-):
-    """Per-step host-driven collect over a functional engine — the SAME
-    signature and trajectory layout as ``build_collect_fn``, but one
-    jitted ``step`` dispatch per env step with the served batch pulled
-    to the host each step (``np.asarray`` on the observations), exactly
-    what a driver that never scans pays.  This is the A/B baseline the
-    ``--resident`` benchmark gate measures the scan loop against."""
-    if not is_functional(pool):
-        raise ValueError("build_stepwise_collect_fn needs a functional "
-                         "(device-family) engine")
-    jit_step = jax.jit(pool.step)
-
-    def collect(ps: PoolState, params: Any, last_ts: TimeStep,
-                key: jax.Array):
-        ts = last_ts
-        steps, acts = [], []
-        for k in jax.random.split(key, num_steps):
-            # the host round-trip the scan loop deletes: the batch is
-            # materialized on the host before the policy runs
-            obs = np.asarray(ts.obs)
-            actions = policy_fn(params, jnp.asarray(obs), k)
-            steps.append(ts)
-            acts.append(actions)
-            ps, ts = jit_step(ps, actions, ts.env_id)
-        traj = tree_stack(steps)
-        return ps, ts, traj, jnp.stack(acts)
-
-    return collect
 
 
 def build_pipelined_collect_fn(

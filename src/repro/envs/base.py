@@ -70,7 +70,7 @@ class Environment:
     def finalize_step(self, state: Any, cost: jnp.ndarray) -> tuple[Any, TimeStep]:
         """Tail of a step after all substeps ran: episode bookkeeping,
         termination, auto-reset.  Shared by the full ``step`` and the
-        masked-tick engine (which runs substeps one tick at a time)."""
+        batched envs' fused multi-substep (``envs/batch.py``)."""
         spec = self.spec
         state = state.replace(t=state.t + 1)
         reward = state.reward_acc

@@ -3,7 +3,7 @@ of what the compiler did.
 
 ``enable_compile_cache()`` is the one place that turns the cache on, for
 every entry point that compiles at deployment size (``chip_smoke.py``,
-``examples/ppo_atari.py``, ``benchmarks/bench_throughput.py``):
+``examples/ppo_atari.py``):
 
   * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and this
     module sets no other directory;

@@ -3,9 +3,8 @@ histograms with labeled series, JSON snapshot/export.
 
 One process-local sink every reporting surface feeds: the engines'
 ``stats()`` snapshots (``publish_pool_stats``), ``DecodePool``'s
-``ServeStats`` (``publish_serve_stats``), the PPO ``history`` records
-(``publish_history``), and the bench artifacts (the ``--obs`` bench
-embeds ``registry.snapshot()`` in ``BENCH_obs.json``).
+``ServeStats`` (``publish_serve_stats``) and the PPO ``history``
+records (``publish_history``).
 
 Design notes:
 

@@ -4,7 +4,7 @@ The device engines cannot be profiled from the host without breaking
 their own thesis (the state never leaves the mesh), so the engine
 counts itself: a fixed-size pytree of integer counters rides on
 ``PoolState`` exactly like ``tf_state`` and is updated INSIDE the
-jitted ``_serve``/``_recv_topm``/``_recv_masked``/``_tick`` bodies.
+jitted ``_recv_topm`` body.
 Counters cross to the host only on an explicit ``pool.stats()``
 snapshot — never on the hot path.
 
@@ -36,8 +36,7 @@ Counter semantics (shared by both implementations):
   * ``serves[i]``      — times lane ``i`` was served in a recv block
     (reset results count: a serve is a served result, stepped or not);
   * ``wait_ticks[i]``  — cumulative recv-ticks lane ``i``'s results
-    waited between becoming available (action enqueued, or — masked
-    mode — step completed) and being served;
+    waited between their action being enqueued and being served;
   * ``wait_hist``      — fixed-edge histogram of those per-serve waits
     (edges ``WAIT_EDGES``, last bucket open-ended);
   * ``served``         — total served result slots (recvs x M);
@@ -186,22 +185,6 @@ def record_serve(
     )
 
 
-@jax.named_scope(TELEMETRY)
-def record_finished(tele: Telemetry, finished, cost) -> Telemetry:
-    """Masked-mode substep accounting: lanes whose step completed this
-    tick (``_tick`` body).  The serve itself is recorded later by
-    ``record_serve`` with ``stepped_mask=False`` — stepped/cost belong
-    to the tick that finished the work, serves to the recv."""
-    import jax.numpy as jnp
-
-    return tele.replace(
-        stepped=tele.stepped + jnp.sum(finished.astype(jnp.int32)),
-        cost_sum=tele.cost_sum + jnp.sum(
-            jnp.where(finished, cost.astype(jnp.int32), 0)
-        ),
-    )
-
-
 # --------------------------------------------------------------------- #
 # host-side snapshot formatting (ONE implementation for every engine)
 # --------------------------------------------------------------------- #
@@ -334,7 +317,6 @@ __all__ = [
     "Telemetry",
     "format_stats",
     "init_telemetry",
-    "record_finished",
     "record_serve",
     "snapshot_device",
     "stats_to_jsonable",

@@ -47,12 +47,13 @@ import jax
 
 # device phases of the pool (core/engine.py, core/scheduler.py,
 # obs/telemetry.py) and of the collect loop (core/xla_loop.py)
-SEND = "pool.send"              # action and progress scatters, step cost
+SEND = "pool.send"              # action scatter, step cost
 SELECT = "pool.select"          # the scheduler: select, enqueue, complete
 GATHER = "pool.gather"          # served lanes' state, action, result rows
 ENV_STEP = "pool.env_step"      # the batched env's step and observe
 WRITEBACK = "pool.writeback"    # scatter of the stepped states and results
-TICK = "pool.tick"              # the masked engine's substep loop
+# no engine emits TICK; it stays because bench/lib/phases.py mirrors SCOPES
+TICK = "pool.tick"
 TELEMETRY = "pool.telemetry"    # in-graph counters
 TRANSFORM = "pool.transform"    # the transform pipeline at serve time
 COLLECT = "collect"             # the collect scan, outside the pool

@@ -4,8 +4,8 @@ end-to-end integration.
 ``train(pool, cfg)`` is the engine-agnostic entry: it dispatches on the
 ``core.protocol`` contract — functional (device-family) pools get the
 fully-jitted on-device driver, host pools the numpy driver — so the
-same call works over `device`, `device-masked`, `device-sharded`,
-`thread`, `forloop`, and `subprocess`.
+same call works over `device`, `device-sharded`, `thread`, `forloop`,
+and `subprocess`.
 
   * ``train_device``: fully device-resident — collect (``lax.scan``
     over the mesh engine, paper App. E) and the PPO update are ONE
@@ -43,10 +43,9 @@ same call works over `device`, `device-masked`, `device-sharded`,
   * ``train_host``: numpy loop over a host engine (thread / subprocess /
     for-loop) with the SAME jitted update — this is the configuration the
     paper's Figure 4 profiles (env-step vs inference vs train vs other
-    timing), reproduced in benchmarks/bench_ppo_profile.py.  Each
-    profile bucket is closed only after ``block_until_ready`` on that
-    stage's outputs, so async XLA dispatch cannot leak one bucket's
-    work into the next.
+    timing).  Each profile bucket is closed only after
+    ``block_until_ready`` on that stage's outputs, so async XLA dispatch
+    cannot leak one bucket's work into the next.
   * ``train_host_pipelined``: the same pipeline over a host engine —
     an actor thread steps the pool (inference behind the latest
     published params) and streams every served batch into a
@@ -70,7 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.device_pool import DeviceEnvPool
+from repro.core.engine import MeshEnvPool
 from repro.core.protocol import EnvPool, is_functional
 from repro.obs.metrics import MetricsRegistry, publish_history
 from repro.obs.trace import PPO_COLLECT, PPO_GAE, PPO_UPDATE, Tracer
@@ -413,7 +412,7 @@ def init_train_device(pool, cfg: PPOConfig, seed: int = 0,
 
 
 def train_device(
-    pool: "DeviceEnvPool | Any",   # any mesh engine (device/device-sharded)
+    pool: "MeshEnvPool | Any",   # any mesh engine (device/device-sharded)
     cfg: PPOConfig,
     seed: int = 0,
     log_fn: Callable[[dict], None] | None = None,
@@ -445,7 +444,7 @@ def train_device(
 # correction — see the module docstring)
 # --------------------------------------------------------------------- #
 def train_pipelined(
-    pool: "DeviceEnvPool | Any",   # any mesh engine (device/device-sharded)
+    pool: "MeshEnvPool | Any",   # any mesh engine (device/device-sharded)
     cfg: PPOConfig,
     seed: int = 0,
     log_fn: Callable[[dict], None] | None = None,

@@ -18,8 +18,7 @@ Two disciplines, one compiled program:
 * ``continuous=False``: run-to-completion static batching — the next
   batch is admitted only when EVERY lane has finished, so short
   requests idle behind the batch's longest one (the padding waste
-  continuous batching exists to reclaim; ``bench_throughput --decode``
-  gates the ratio).
+  continuous batching exists to reclaim).
 
 The host-side request queue is scheduler-fed: ``schedule="fifo"`` keeps
 arrival order, ``"sjf"`` admits shortest-total-work first (the

@@ -97,7 +97,6 @@ def main() -> None:
     for tag, engine, n, m, kw in [
         ("device_sync", "device", 8, None, {}),
         ("device_async", "device", 8, 4, {}),
-        ("masked", "device-masked", 8, 4, {}),
         ("sharded_async", "device-sharded", 8, 4, {"num_shards": 1}),
     ]:
         i, r, d, o = device_stream(engine, n, m, **kw)

@@ -13,9 +13,7 @@ forces D simulated host devices before jax locks the platform.  Checks:
     ``stepped <= served``, per-lane serves sum to ``served``;
   * the hierarchical scheduler's ``overdue_admits`` counter is wired
     through ``select_info`` at a real mesh (TokenSkew async forces the
-    overdue band to fire);
-  * ``obs=False`` on the sharded engine raises on ``stats()`` (the
-    counters were stripped, not zeroed).
+    overdue band to fire).
 
 Prints one JSON object; the parent test asserts on it.
 
@@ -89,16 +87,6 @@ def main() -> dict:
         ps, ts = step(ps, a, ts.env_id)
     hs = pool.stats(ps)
     res["hier_overdue_counted"] = int(hs["overdue_admits"]) > 0
-
-    # obs=False strips the counters on the sharded engine too
-    pool = make(TASK, num_envs=N, engine="device-sharded", num_shards=D,
-                obs=False, seed=SEED)
-    ps, _ = pool.reset(jax.random.PRNGKey(SEED))
-    try:
-        pool.stats(ps)
-        res["obs_off_raises"] = False
-    except RuntimeError:
-        res["obs_off_raises"] = True
     return res
 
 

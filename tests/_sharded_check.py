@@ -21,7 +21,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.core.registry import make  # noqa: E402
-from repro.core.sharded_pool import ShardedDeviceEnvPool  # noqa: E402
+from repro.core.engine import MeshEnvPool  # noqa: E402
 from repro.core.xla_loop import build_random_collect_fn  # noqa: E402
 
 STEPS = 8
@@ -83,7 +83,7 @@ def main() -> dict:
     # 4) divisibility validation needs a real multi-device mesh
     try:
         env = pool.env
-        ShardedDeviceEnvPool(env, num_envs=D + 1, mesh=D)
+        MeshEnvPool(env, num_envs=D + 1, mesh=D)
         res["divisibility_raises"] = False
     except ValueError:
         res["divisibility_raises"] = True

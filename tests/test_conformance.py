@@ -166,7 +166,6 @@ def golden_device_stream(engine, n, m, **kw):
 @pytest.mark.parametrize("tag,engine,n,m,kw", [
     ("device_sync", "device", 8, None, {}),
     ("device_async", "device", 8, 4, {}),
-    ("masked", "device-masked", 8, 4, {}),
     ("sharded_async", "device-sharded", 8, 4, {"num_shards": 1}),
 ])
 def test_fifo_bitwise_matches_pre_refactor_golden(tag, engine, n, m, kw):
@@ -355,7 +354,6 @@ def ant_rollout(engine, batched, steps=25, n=8, m=None, num_shards=None):
 @pytest.mark.parametrize("engine,m,shards", [
     ("device", None, None),           # sync
     ("device", 4, None),              # async top-M
-    ("device-masked", 4, None),       # event-driven tick ablation
     ("device-sharded", None, 1),      # shard_map body
 ])
 def test_batched_native_matches_vmap_lifted(engine, m, shards):
@@ -401,42 +399,3 @@ def test_batched_native_ant_matches_host_per_lane_engine():
         np.testing.assert_array_equal(di, hi, err_msg=f"ids step {t}")
         np.testing.assert_array_equal(dr, hr, err_msg=f"reward step {t}")
         np.testing.assert_array_equal(dd, hd, err_msg=f"done step {t}")
-
-
-def test_masked_mode_conforms_to_async():
-    """Masked (event-driven tick) mode must serve the SAME per-env
-    reward/obs streams as the top-M async engine — the conformance
-    contract previously asserted only between sync and async."""
-
-    def run(engine):
-        pool = make(TASK, num_envs=8, batch_size=4, engine=engine, seed=SEED)
-        ps, ts = pool.reset(jax.random.PRNGKey(SEED))
-        step = jax.jit(pool.step)
-        counts = np.zeros(8, int)
-        streams: dict[int, list] = {i: [] for i in range(8)}
-        for _ in range(16):
-            ids = np.asarray(ts.env_id)
-            obs = np.asarray(ts.obs)
-            rew = np.asarray(ts.reward)
-            for j, e in enumerate(ids):
-                streams[int(e)].append((rew[j], obs[j]))
-            # deterministic per-(env, local-step) action
-            a = jnp.asarray((counts[ids] * 7 + ids) % VOCAB, jnp.int32)
-            counts[ids] += 1
-            ps, ts = step(ps, a, ts.env_id)
-        return streams
-
-    sa = run("device")        # N=8 M=4 -> async
-    sm = run("device-masked")
-    for e in range(8):
-        n = min(len(sa[e]), len(sm[e]))
-        assert n > 0
-        for k in range(n):
-            np.testing.assert_array_equal(
-                sa[e][k][0], sm[e][k][0],
-                err_msg=f"masked reward stream diverges (env {e}, serve {k})",
-            )
-            np.testing.assert_array_equal(
-                sa[e][k][1], sm[e][k][1],
-                err_msg=f"masked obs stream diverges (env {e}, serve {k})",
-            )

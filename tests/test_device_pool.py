@@ -1,11 +1,12 @@
-"""DeviceEnvPool semantics: the paper's engine invariants, TPU-native."""
+"""MeshEnvPool semantics: the paper's engine invariants, TPU-native."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.device_pool import DeviceEnvPool
+import repro
+from repro.core.engine import MeshEnvPool
 from repro.envs.classic import CartPole
 from repro.envs.mujoco_like import MujocoLike
 
@@ -25,7 +26,7 @@ def roll(pool, steps=30, seed=0):
 def test_sync_equals_direct_vmap():
     """sync pool over N must equal directly vmapped env stepping."""
     env = CartPole()
-    pool = DeviceEnvPool(env, 4, 4, mode="sync")
+    pool = MeshEnvPool(env, 4, 4, mode="sync")
     ps = pool.init(jax.random.PRNGKey(0))
 
     # manual reference: same seeds -> same init states
@@ -45,10 +46,10 @@ def test_sync_equals_direct_vmap():
 
 
 @pytest.mark.parametrize("mode,N,M", [
-    ("sync", 8, 8), ("async", 8, 4), ("async", 16, 4), ("masked", 8, 4),
+    ("sync", 8, 8), ("async", 8, 4), ("async", 16, 4),
 ])
 def test_batch_shape_and_ids(mode, N, M):
-    pool = DeviceEnvPool(MujocoLike(), N, M, mode=mode)
+    pool = MeshEnvPool(MujocoLike(), N, M, mode=mode)
     ps, ts = pool.reset(jax.random.PRNGKey(0))
     assert ts.env_id.shape == (M,)
     assert len(set(np.asarray(ts.env_id).tolist())) == M  # distinct envs
@@ -61,7 +62,7 @@ def test_batch_shape_and_ids(mode, N, M):
 
 def test_no_starvation_async():
     """Aging must guarantee every env is served (paper §3.3 long-tail)."""
-    pool = DeviceEnvPool(MujocoLike(), 16, 4, mode="async", aging=1.0)
+    pool = MeshEnvPool(MujocoLike(), 16, 4, mode="async", aging=1.0)
     _, _, seen = roll(pool, steps=60)
     counts = np.bincount(seen, minlength=16)
     assert counts.min() > 0, counts
@@ -71,8 +72,8 @@ def test_no_starvation_async():
 
 def test_async_m_equals_n_matches_sync():
     env = CartPole()
-    sync = DeviceEnvPool(env, 6, 6, mode="sync")
-    asy = DeviceEnvPool(env, 6, 6, mode="async")
+    sync = MeshEnvPool(env, 6, 6, mode="sync")
+    asy = MeshEnvPool(env, 6, 6, mode="async")
     ps_s, ts_s = sync.reset(jax.random.PRNGKey(3))
     ps_a, ts_a = asy.reset(jax.random.PRNGKey(3))
     for i in range(10):
@@ -93,7 +94,7 @@ def test_env_id_routing():
     env k twice with the same action from the same state is deterministic,
     regardless of batch position."""
     env = CartPole()
-    pool = DeviceEnvPool(env, 8, 4, mode="async")
+    pool = MeshEnvPool(env, 8, 4, mode="async")
     ps, ts = pool.reset(jax.random.PRNGKey(0))
     # send actions labeled by env_id; observation for env k must evolve by
     # env k's dynamics (check obs corresponds to stored env state)
@@ -107,42 +108,8 @@ def test_env_id_routing():
         )
 
 
-def test_masked_and_topm_agree_on_uniform_cost():
-    """Engine-equivalence property: driven by per-env deterministic
-    actions, both async engines must produce the SAME per-env observation
-    stream.  (Final internal states are phase-skewed by design: the top-M
-    engine defers execution of pending actions, the masked engine is
-    eager — so we compare served streams, not states.)"""
-    env = CartPole()
-
-    def run(mode):
-        pool = DeviceEnvPool(env, 8, 4, mode=mode)
-        ps, ts = pool.reset(jax.random.PRNGKey(1))
-        counts = np.zeros(8, int)
-        streams = {i: [] for i in range(8)}
-        for i in range(12):
-            ids = np.asarray(ts.env_id)
-            obs = np.asarray(ts.obs)
-            for j, e in enumerate(ids):
-                streams[int(e)].append(obs[j])
-            # deterministic per-(env, local step) action
-            a = jnp.asarray((counts[ids] + ids) % 2, env.spec.act_spec.dtype)
-            counts[ids] += 1
-            ps, ts = pool.step(ps, a, ts.env_id)
-        return streams
-
-    sa = run("async")
-    sm = run("masked")
-    for e in range(8):
-        n = min(len(sa[e]), len(sm[e]))
-        assert n > 0
-        np.testing.assert_allclose(
-            np.stack(sa[e][:n]), np.stack(sm[e][:n]), rtol=1e-5, atol=1e-6
-        )
-
-
 def test_xla_handle_api():
-    pool = DeviceEnvPool(CartPole(), 4, 2, mode="async")
+    pool = MeshEnvPool(CartPole(), 4, 2, mode="async")
     handle, recv, send, step = pool.xla()
     ps, ts = recv(handle)
     assert ts.env_id.shape == (2,)
@@ -154,11 +121,27 @@ def test_xla_handle_api():
 def test_validation_errors():
     env = CartPole()
     with pytest.raises(ValueError):
-        DeviceEnvPool(env, 4, 8)
+        MeshEnvPool(env, 4, 8)
     with pytest.raises(ValueError):
-        DeviceEnvPool(env, 4, 2, mode="sync")
+        MeshEnvPool(env, 4, 2, mode="sync")
     with pytest.raises(ValueError):
-        DeviceEnvPool(env, 4, 4, mode="weird")
+        MeshEnvPool(env, 4, 4, mode="weird")
+
+
+# the event-driven tick ablation's mode, which the engine no longer has
+TICK_MODE = "masked"
+
+
+@pytest.mark.parametrize("build", [
+    lambda: repro.make("CartPole-v1", num_envs=4, batch_size=2,
+                       engine=f"device-{TICK_MODE}"),
+    lambda: MeshEnvPool(CartPole(), 4, 2, mode=TICK_MODE),
+], ids=["make-engine", "pool-mode"])
+def test_tick_mode_is_rejected(build):
+    """The device engine has one recv path: neither the engine name nor
+    the mode of the tick ablation builds a pool."""
+    with pytest.raises(ValueError, match=TICK_MODE):
+        build()
 
 
 def test_sync_blocks_come_in_lane_order_at_one_shard():

@@ -4,17 +4,19 @@
   registered env and for the leaves the slab treats specially: NaN and
   negative-zero float patterns, ``bool``, raw PRNG keys, int32
   extremes, and 8- and 16-bit leaves widened to a word each, also
-  through a pool's send, recv and masked tick.  A typed PRNG key or a
-  64-bit leaf is refused when the layout is built.
+  through a pool's send and recv.  A typed PRNG key or a 64-bit leaf is
+  refused when the layout is built.
+* A lane row holds the env state, the action and the seven stored
+  results, and nothing else; a send rewrites only the sent lanes'
+  actions.
 * Compiled for a described v5e at the ``ant_async_sim`` shapes (Ant-v3,
   N=4096, M=1024), a recv moves the lane rows with one gather and one
   scatter.  Before the slab, with one array per leaf, the recv held 19
   scatters, 25 gathers and 3 sorts of the indices of its ``pred``
-  scatters, and the send 5 scatters and 5 gathers.  The masked recv
-  held 16 gathers and 1 scatter (``phase``), and the compiler counted
-  187195904 bytes accessed.
+  scatters, and the send 5 scatters and 5 gathers.
 """
 
+import math
 import os
 import re
 
@@ -142,6 +144,53 @@ def test_leaf_wider_than_a_word_is_refused(leaf):
 
 
 # --------------------------------------------------------------------- #
+# what a lane row holds, and what a send writes to it
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("task,words", [
+    ("Ant-v3", 49), ("PongClassic-v5", 22), ("CartPole-v1", 17),
+    ("TokenCopy-v0", 79),
+])
+def test_lane_row_is_state_action_and_results(task, words):
+    """One word per element of the env state and of the action, plus the
+    seven stored results (reward, done, terminated, truncated, episode
+    return and length, step cost): the engine keeps no column of its
+    own in the slab."""
+    pool = repro.make(task, num_envs=N, seed=0)
+    state = jax.eval_shape(pool.env.init_state, jax.random.PRNGKey(0))
+    state_words = sum(math.prod(x.shape) for x in jax.tree.leaves(state))
+    action_words = math.prod(pool.spec.act_spec.shape)
+    assert pool.lanes.words == state_words + action_words + 7 == words
+
+
+@pytest.mark.parametrize("task,n,m", [
+    ("Ant-v3", 8, 4), ("CartPole-v1", 4, 4),
+], ids=["async-ant", "sync-cartpole"])
+def test_send_rewrites_only_the_sent_lanes_actions(task, n, m):
+    pool = repro.make(task, num_envs=n, batch_size=m, seed=0)
+    ps, ts = pool.reset(jax.random.PRNGKey(3))
+    act = pool.spec.act_spec
+    actions = jnp.ones((m,) + act.shape, act.dtype)
+    sent = np.asarray(ts.env_id)
+    before = pool.lane_view(ps)
+    assert not np.array_equal(np.asarray(before.actions)[sent], actions)
+    ps2 = jax.jit(pool.send)(ps, actions, ts.env_id)
+    after = pool.lane_view(ps2)
+
+    def rows(view, ids):
+        return jax.tree.map(lambda x: x[ids], view)
+
+    # the sent lanes: the new action, every other field bit for bit
+    _assert_bitwise(rows(after, sent).actions, actions)
+    _assert_bitwise(rows(after.replace(actions=before.actions), sent),
+                    rows(before, sent))
+    # the other lanes: whole slab rows bit for bit
+    others = np.setdiff1d(np.arange(n), sent)
+    assert others.size == n - m
+    np.testing.assert_array_equal(np.asarray(ps2.lanes)[others],
+                                  np.asarray(ps.lanes)[others])
+
+
+# --------------------------------------------------------------------- #
 # an env with a byte-wide leaf, through every device engine
 # --------------------------------------------------------------------- #
 @pytree_dataclass
@@ -161,9 +210,8 @@ class LoggedCartPole(CartPole):
 
 
 @pytest.mark.parametrize("kw", [
-    dict(batch_size=4), dict(batch_size=4, mode="masked"),
-    dict(batch_size=8), dict(batch_size=4, mesh=1),
-], ids=["async", "masked", "sync", "mesh1"])
+    dict(batch_size=4), dict(batch_size=8), dict(batch_size=4, mesh=1),
+], ids=["async", "sync", "mesh1"])
 def test_byte_leaf_rides_through_the_engine(kw):
     logged = MeshEnvPool(LoggedCartPole(), 8, **kw)
     plain = MeshEnvPool(CartPole(), 8, **kw)
@@ -251,21 +299,3 @@ def test_ant_recv_and_send_move_lane_rows_in_one_op(topo):
                 if re.search(r"pred\[\d+\]", ln.split(" sort(")[0])]
     assert len(_ops(send, "scatter")) <= 4
     assert len(_ops(send, "gather")) <= 3
-
-
-def test_ant_masked_recv_unpacks_and_packs_the_slab_once(topo):
-    pool, _, ps = _ant_on_chip(topo, mode="masked")
-    compiled = pool._jit_recv.lower(ps).compile()
-    recv = compiled.as_text()
-    # the served rows: one slab gather, beside telemetry's read of the
-    # wait and the env's two strided column reads (parent: 16 gathers)
-    slab = [ln for ln in _ops(recv, "gather") if " u32[1024," in ln]
-    assert len(slab) == 1 and len(_ops(recv, "gather")) <= 4
-    assert len(_ops(recv, "scatter")) <= 1    # ``phase``
-    # one pack of all N rows, after the tick loop and not in its body
-    packs = [ln for ln in _ops(recv, "concatenate")
-             if re.search(r" u32\[(4096,\d+|\d+,4096)\]", ln)]
-    assert len(packs) == 1 and "while/body" not in packs[0]
-    cost = compiled.cost_analysis()
-    cost = cost[0] if isinstance(cost, list) else cost
-    assert cost["bytes accessed"] <= 187195904   # the parent's

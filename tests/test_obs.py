@@ -1,5 +1,5 @@
 """Engine-wide telemetry (``obs/``): the ``stats()`` contract across all
-six engines, the metrics registry, and the fenced trace spans.
+five engines, the metrics registry, and the fenced trace spans.
 
 The conformance pin mirrors tests/test_conformance.py: the same scripted
 sync rollout must yield the SAME counter values on every engine — the
@@ -41,7 +41,7 @@ def policy(env_ids: np.ndarray, t: int) -> np.ndarray:
 
 
 # --------------------------------------------------------------------- #
-# stats() conformance: all six engines, one scripted rollout
+# stats() conformance: all five engines, one scripted rollout
 # --------------------------------------------------------------------- #
 def device_stats(engine: str, **kw) -> dict:
     pool = repro.make(TASK, num_envs=N, engine=engine, seed=SEED, **kw)
@@ -92,7 +92,6 @@ def test_stats_identical_across_all_six_engines():
 
     ref_j = stats_to_jsonable(ref)
     for engine, runner, kw in [
-        ("device-masked", device_stats, {"batch_size": N}),
         ("device-sharded", device_stats, {"num_shards": 1}),
         ("thread", host_stats, {"num_threads": 2}),
         ("forloop", host_stats, {}),
@@ -139,19 +138,6 @@ def test_stats_mesh_invariance_subprocess():
     assert res["async_stepped_bounded"], res
     assert res["async_hist_conserved"], res
     assert res["hier_overdue_counted"], res
-    assert res["obs_off_raises"], res
-
-
-def test_obs_false_strips_counters_and_stats_raises():
-    pool = repro.make(TASK, num_envs=N, obs=False, seed=SEED)
-    ps, _ = pool.reset(jax.random.PRNGKey(SEED))
-    assert ps.telemetry == ()                   # zero extra pytree leaves
-    with pytest.raises(RuntimeError, match="obs=False"):
-        pool.stats(ps)
-    hp = repro.make(TASK, num_envs=N, engine="forloop", obs=False,
-                    seed=SEED)
-    with pytest.raises(RuntimeError, match="obs=False"):
-        hp.stats()
 
 
 @pytest.mark.parametrize("engine", ["device", "forloop"])
@@ -450,8 +436,7 @@ _ALL_MODES = {"pool.send", "pool.select", "pool.gather", "pool.env_step",
     ("Ant-v3", "device", 8, 8, _ALL_MODES | {"pool.writeback"}),
     ("AntNorm-v3", "device", 16, 8,
      _ALL_MODES | {"pool.writeback", "pool.transform"}),
-    ("Ant-v3", "device-masked", 16, 8, _ALL_MODES | {"pool.tick"}),
-], ids=["sync", "async", "masked"])
+], ids=["sync", "async"])
 def test_pool_phases_reach_compiled_op_names(task, engine, num_envs, batch,
                                              expect):
     """The random collect over a ``device`` engine, compiled: each phase

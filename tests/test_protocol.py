@@ -1,4 +1,4 @@
-"""core/protocol.py: ONE `EnvPool` contract over all six engines, and
+"""core/protocol.py: ONE `EnvPool` contract over all five engines, and
 the drivers (dm_api / xla_loop / PPO) running unchanged across them."""
 
 import threading
@@ -17,7 +17,7 @@ SEED = 0
 
 
 # --------------------------------------------------------------------- #
-# protocol conformance: all six engines
+# protocol conformance: all five engines
 # --------------------------------------------------------------------- #
 def _make(engine, n=4, m=None):
     kwargs = {}
@@ -33,15 +33,13 @@ def _make(engine, n=4, m=None):
 
 @pytest.mark.parametrize("engine,functional", [
     ("device", True),
-    ("device-masked", True),
     ("device-sharded", True),
     ("thread", False),
     ("forloop", False),
     ("subprocess", False),
 ])
 def test_all_six_engines_satisfy_envpool_protocol(engine, functional):
-    m = 2 if engine == "device-masked" else None
-    pool = _make(engine, 4, m)
+    pool = _make(engine, 4)
     try:
         assert isinstance(pool, EnvPool), engine
         assert is_functional(pool) == functional, engine
@@ -49,7 +47,7 @@ def test_all_six_engines_satisfy_envpool_protocol(engine, functional):
             assert isinstance(pool, FunctionalEnvPool)
         # the spec triple every engine must carry (paper §3.4)
         assert pool.num_envs == 4
-        assert pool.batch_size in (4, 2)
+        assert pool.batch_size == 4
         assert pool.spec.obs_spec.shape
     finally:
         if hasattr(pool, "close"):

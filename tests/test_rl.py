@@ -221,11 +221,11 @@ def test_train_host_pipelined_smoke():
 
 def test_ppo_improves_cartpole():
     """Short-budget learning trend on CartPole (device pool, sync)."""
-    from repro.core.device_pool import DeviceEnvPool
+    from repro.core.engine import MeshEnvPool
     from repro.envs.classic import CartPole
     from repro.rl.ppo import PPOConfig, train_device
 
-    pool = DeviceEnvPool(CartPole(), 16, 16, mode="sync")
+    pool = MeshEnvPool(CartPole(), 16, 16, mode="sync")
     cfg = PPOConfig(total_steps=30_000, num_steps=64, minibatches=4,
                     epochs=4, lr=1e-3)
     _, _, hist = train_device(pool, cfg, seed=1, hidden=(64, 64))
@@ -237,11 +237,11 @@ def test_ppo_improves_cartpole():
 def test_ppo_async_pool_runs():
     """PPO over the ASYNC pool (the paper's headline mode) trains without
     error and routes env_ids correctly."""
-    from repro.core.device_pool import DeviceEnvPool
+    from repro.core.engine import MeshEnvPool
     from repro.envs.mujoco_like import MujocoLike
     from repro.rl.ppo import PPOConfig, train_device
 
-    pool = DeviceEnvPool(MujocoLike(), 16, 8, mode="async")
+    pool = MeshEnvPool(MujocoLike(), 16, 8, mode="async")
     cfg = PPOConfig(total_steps=4_000, num_steps=32, minibatches=2,
                     epochs=2, lr=3e-4)
     _, _, hist = train_device(pool, cfg, seed=0, hidden=(32, 32))

@@ -91,18 +91,6 @@ def test_select_prefers_ready_lanes(schedule):
     assert idx == {0, 2}, idx
 
 
-def test_select_ready_only_returns_ready():
-    ss = SchedState(
-        phase=jnp.array([HAS_ACTION, READY, WAITING_ACTION, READY], jnp.int32),
-        cost=jnp.ones((4,), jnp.int32),
-        send_tick=jnp.array([0, 5, 0, 2], jnp.int32),
-        tick=jnp.int32(6),
-    )
-    idx = np.asarray(FifoScheduler().select_ready(ss, 2))
-    # READY lanes only, completion (send_tick) order
-    np.testing.assert_array_equal(idx, [3, 1])
-
-
 # --------------------------------------------------------------------- #
 # policy semantics
 # --------------------------------------------------------------------- #

@@ -1,4 +1,4 @@
-"""ShardedDeviceEnvPool: single-shard equivalence in-process, multi-shard
+"""The ``device-sharded`` engine: single-shard equivalence in-process, multi-shard
 invariance via a subprocess with simulated host devices (the tier-1
 process itself must see ONE device — conftest harness contract)."""
 
@@ -12,8 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.device_pool import DeviceEnvPool
-from repro.core.sharded_pool import ShardedDeviceEnvPool, make_env_mesh
+from repro.core.engine import MeshEnvPool, make_env_mesh
 from repro.core.xla_loop import build_random_collect_fn
 from repro.envs.classic import CartPole
 from repro.envs.token_env import TokenEnv
@@ -40,10 +39,11 @@ def scripted_rollout(pool, env, steps=10, seed=0):
 
 
 def test_mesh1_matches_plain_device_pool():
-    """D=1 sharding must be a bitwise no-op vs DeviceEnvPool (sync)."""
+    """An explicit D=1 mesh must be a bitwise no-op vs the default
+    one-device pool (sync)."""
     env = TokenEnv()
-    plain = DeviceEnvPool(env, 8, 8, mode="sync")
-    sharded = ShardedDeviceEnvPool(env, 8, mesh=1)
+    plain = MeshEnvPool(env, 8, 8, mode="sync")
+    sharded = MeshEnvPool(env, 8, mesh=1)
     for (i1, r1, o1), (i2, r2, o2) in zip(
         scripted_rollout(plain, env), scripted_rollout(sharded, env)
     ):
@@ -55,7 +55,7 @@ def test_mesh1_matches_plain_device_pool():
 def test_sync_output_is_env_id_ordered():
     """Sharded sync batches are emitted in env-id order (the property
     that makes rollouts independent of per-shard top-k cost ordering)."""
-    pool = ShardedDeviceEnvPool(TokenEnv(), 8, mesh=1)
+    pool = MeshEnvPool(TokenEnv(), 8, mesh=1)
     ps, ts = pool.reset(jax.random.PRNGKey(0))
     step = jax.jit(pool.step)
     for t in range(3):
@@ -65,7 +65,7 @@ def test_sync_output_is_env_id_ordered():
 
 
 def test_async_mode_unique_ids():
-    pool = ShardedDeviceEnvPool(CartPole(), 8, batch_size=4, mesh=1)
+    pool = MeshEnvPool(CartPole(), 8, batch_size=4, mesh=1)
     assert pool.mode == "async"
     ps, ts = pool.reset(jax.random.PRNGKey(0))
     step = jax.jit(pool.step)
@@ -81,7 +81,7 @@ def test_async_mode_unique_ids():
 
 def test_scan_rollout_under_jit():
     """The whole collect loop lowers into one lax.scan over the pool."""
-    pool = ShardedDeviceEnvPool(TokenEnv(), 8, mesh=1)
+    pool = MeshEnvPool(TokenEnv(), 8, mesh=1)
     collect = build_random_collect_fn(pool, num_steps=7)
     ps, ts = pool.reset(jax.random.PRNGKey(0))
     ps, ts, traj, acts = collect(ps, None, ts, jax.random.PRNGKey(1))
@@ -91,7 +91,7 @@ def test_scan_rollout_under_jit():
 
 
 def test_xla_handle_api():
-    pool = ShardedDeviceEnvPool(CartPole(), 4, batch_size=2, mesh=1)
+    pool = MeshEnvPool(CartPole(), 4, batch_size=2, mesh=1)
     handle, recv, send, step = pool.xla()
     ps, ts = recv(handle)
     assert ts.env_id.shape == (2,)
@@ -105,7 +105,7 @@ def test_validation_errors():
     with pytest.raises(ValueError):
         make_env_mesh(len(jax.devices()) + 1)
     with pytest.raises(ValueError):
-        ShardedDeviceEnvPool(env, 4, batch_size=8, mesh=1)
+        MeshEnvPool(env, 4, batch_size=8, mesh=1)
 
 
 def test_multi_shard_invariance_subprocess():
