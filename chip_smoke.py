@@ -7,9 +7,10 @@ One chip runs four phases, in this order, in this one process:
 
   1. kernel parity: every Pallas kernel of the main paths (``env_step``
      masked, ``grayscale``, ``resize``, ``crop``, ``pong_render``,
-     ``decode_attention``) compiled for the chip against its
-     ``reference`` backend — the integer image family bitwise, the float
-     kernels within ``ENV_TOL`` / ``DECODE_TOL`` — with the kernel
+     ``minibatch_obs``, ``decode_attention``) compiled for the chip
+     against its ``reference`` backend — the integer image family and
+     the PPO minibatch operand bitwise, the float kernels within
+     ``ENV_TOL`` / ``DECODE_TOL`` — with the kernel
      (``tpu_custom_call``) asserted in each compiled program;
   2. the Atari PPO trainer: ``repro.make("PongClassic-v5")`` on the
      ``device`` engine through ``rl/ppo.py::train_device`` with the
@@ -84,6 +85,7 @@ def kernel_parity(seed: int) -> None:
     from repro.kernels.decode_attention.ops import decode_attention
     from repro.kernels.env_step.ops import env_multi_step
     from repro.kernels.image import ops as image
+    from repro.kernels.minibatch_obs.ops import minibatch_obs
 
     kernel = resolve_backend("auto")
 
@@ -141,6 +143,17 @@ def kernel_parity(seed: int) -> None:
     pos = [jax.random.uniform(k, (n,), minval=0.0, maxval=84.0)
            for k in jax.random.split(ks[5], 4)]
     bitwise("pong_render", image.pong_render, *pos)
+
+    # the PPO update's conv1 operand: every uint8 value, then one
+    # minibatch of 32768 stacks out of a T=128 x N=1024 rollout
+    every = (jnp.arange(2 * 4 * 84 * 84) % 256).astype(jnp.uint8)
+    bitwise("minibatch_obs_every_value", minibatch_obs,
+            every.reshape(2, 4, 84, 84), jnp.array([1, 0], jnp.int32))
+    rows = jax.random.randint(ks[8], (128 * n, 4, 84, 1), 0, 256)
+    plane = jax.random.randint(ks[9], (4, 84, 84), 0, 256)
+    rollout = jax.jit(lambda r, p: (r ^ p).astype(jnp.uint8))(rows, plane)
+    idx = jax.random.permutation(ks[10], 128 * n)[:32 * n]
+    bitwise("minibatch_obs", minibatch_obs, rollout, idx)
 
     # decode_attention: a serving cache (B=32, T=1024) and the LM
     # policy's default shape (rl/policy_lm.py: H=4, Hkv=2, hd=16, T=64)

@@ -4,6 +4,8 @@
   env_step/         the paper's env-execution hot loop on the VPU
   image/            batched image preprocessing (grayscale / resize /
                     crop) + the Atari RGB render — the CuLE argument
+  minibatch_obs/    a PPO minibatch's conv1 operand: gather, scale to
+                    bfloat16, batch-minor layout in one pass
 
 Each has kernel.py (pl.pallas_call + BlockSpec), ops.py (jit wrapper),
 ref.py (pure-jnp oracle).  backend.py states the shared TPU/fallback
@@ -24,6 +26,7 @@ from repro.kernels.decode_attention.ops import (
 from repro.kernels.env_step.ops import env_multi_step, env_step
 from repro.kernels.flash_attention.ops import flash_attention, mha_reference
 from repro.kernels.image.ops import crop, grayscale, pong_render, resize
+from repro.kernels.minibatch_obs.ops import minibatch_obs
 
 __all__ = [
     "BACKENDS",
@@ -36,6 +39,7 @@ __all__ = [
     "flash_attention",
     "grayscale",
     "mha_reference",
+    "minibatch_obs",
     "pong_render",
     "resize",
     "resolve_backend",

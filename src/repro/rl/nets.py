@@ -34,10 +34,10 @@ def _conv(key, cin, cout, kh, kw):
     }
 
 
-def _apply_conv(p, x, stride):
+def _apply_conv(p, x, stride, lhs="NHWC"):
     out = jax.lax.conv_general_dilated(
         x, p["w"], window_strides=(stride, stride), padding="VALID",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        dimension_numbers=(lhs, "HWIO", "NHWC"),
     )
     return out + p["b"]
 
@@ -76,11 +76,20 @@ class ActorCritic:
             p["log_std"] = jnp.zeros((self.act_dim,), jnp.float32)
         return p
 
-    def trunk(self, p: dict[str, Any], obs: jnp.ndarray) -> jnp.ndarray:
+    def trunk(self, p: dict[str, Any], obs: jnp.ndarray,
+              batch_minor: bool = False) -> jnp.ndarray:
+        """Features of a batch of observations.  With ``batch_minor``, a
+        pixel net's ``obs`` is conv1's operand already made: ``obs /
+        255`` in bfloat16, laid out ``(H, W, C, N)``
+        (``kernels/minibatch_obs``); widening it to float32 is exact,
+        and conv1 rounds it back at ``Precision.DEFAULT``."""
         if self.pixel:
-            x = obs.astype(jnp.float32) / 255.0
-            x = jnp.transpose(x, (0, 2, 3, 1))  # NCHW -> NHWC
-            x = jax.nn.relu(_apply_conv(p["conv1"], x, 4))
+            if batch_minor:
+                x, lhs = obs.astype(jnp.float32), "HWCN"
+            else:
+                x = obs.astype(jnp.float32) / 255.0
+                x, lhs = jnp.transpose(x, (0, 2, 3, 1)), "NHWC"
+            x = jax.nn.relu(_apply_conv(p["conv1"], x, 4, lhs))
             x = jax.nn.relu(_apply_conv(p["conv2"], x, 2))
             x = jax.nn.relu(_apply_conv(p["conv3"], x, 1))
             x = x.reshape(x.shape[0], -1)
@@ -90,9 +99,10 @@ class ActorCritic:
             x = jax.nn.elu(_apply_dense(p[f"mlp{i}"], x))
         return x
 
-    def forward(self, p: dict[str, Any], obs: jnp.ndarray):
+    def forward(self, p: dict[str, Any], obs: jnp.ndarray,
+                batch_minor: bool = False):
         """Returns (logits_or_mean, value)."""
-        feat = self.trunk(p, obs)
+        feat = self.trunk(p, obs, batch_minor)
         pi = _apply_dense(p["pi"], feat)
         v = _apply_dense(p["v"], feat)[..., 0]
         return pi, v
@@ -113,8 +123,8 @@ class ActorCritic:
         )
         return a.astype(jnp.float32), logp, v, pi
 
-    def logp_entropy(self, p, obs, actions):
-        pi, v = self.forward(p, obs)
+    def logp_entropy(self, p, obs, actions, batch_minor: bool = False):
+        pi, v = self.forward(p, obs, batch_minor)
         if self.discrete:
             ls = jax.nn.log_softmax(pi)
             logp = ls[jnp.arange(actions.shape[0]), actions.astype(jnp.int32)]

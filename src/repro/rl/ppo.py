@@ -71,6 +71,12 @@ import numpy as np
 
 from repro.core.engine import MeshEnvPool
 from repro.core.protocol import EnvPool, is_functional
+from repro.kernels.backend import resolve_backend
+from repro.kernels.minibatch_obs.ops import (
+    minibatch_obs,
+    supports,
+    uses_kernel,
+)
 from repro.obs.metrics import MetricsRegistry, publish_history
 from repro.obs.trace import PPO_COLLECT, PPO_GAE, PPO_UPDATE, Tracer
 from repro.rl.gae import gae
@@ -110,20 +116,30 @@ class PPOState:
 
 
 def make_ppo_update(net: ActorCritic, cfg: PPOConfig, total_updates: int,
-                    keep_steps: bool = False):
+                    keep_steps: bool = False, learner_devices: int = 1):
     """``(opt, update)``: ``update(state, rollout, key) -> (state,
     metrics)`` runs every epoch's minibatch steps.  With ``keep_steps``,
     ``metrics["steps"]`` holds ``first``, the learner state after the
     update's first minibatch step, and ``last``, the state before its
     last one: what it takes to check those two steps one at a time,
-    each from the program's own parameters."""
+    each from the program's own parameters.
+
+    A pixel net over uint8 stacks takes each minibatch's conv1 operand
+    from ``kernels/minibatch_obs`` wherever the kernel backend is not
+    the off-TPU ``vmap`` choice and the learner runs on one device
+    (``learner_devices``; XLA cannot partition a Pallas call over the
+    mesh ``train_device`` replicates the update on): the gather, the
+    scaling to bfloat16 and the batch-minor layout in one pass, the same
+    bits as XLA's.  ``metrics["obs_kernel_steps"]`` counts the minibatch
+    steps whose operand the Pallas kernel made."""
     opt = adamw(b1=0.9, b2=0.999, eps=1e-5, weight_decay=0.0,
                 clip_norm=cfg.max_grad_norm)
     lr_fn = (linear_decay(cfg.lr, total_updates) if cfg.anneal_lr
              else (lambda s: cfg.lr))
 
-    def loss_fn(params, batch):
-        logp, ent, v = net.logp_entropy(params, batch["obs"], batch["actions"])
+    def loss_fn(params, batch, batch_minor):
+        logp, ent, v = net.logp_entropy(params, batch["obs"], batch["actions"],
+                                        batch_minor)
         ratio = jnp.exp(logp - batch["logp"])
         adv = batch["adv"]
         adv = (adv - adv.mean()) / (adv.std() + 1e-8)
@@ -145,6 +161,7 @@ def make_ppo_update(net: ActorCritic, cfg: PPOConfig, total_updates: int,
                       "ratio": jnp.mean(ratio)}
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    backend = resolve_backend()
 
     def update(state: PPOState, rollout: dict[str, jnp.ndarray], key: jax.Array):
         """rollout leaves: (T, M, ...) — flattened to (T*M, ...)."""
@@ -152,6 +169,10 @@ def make_ppo_update(net: ActorCritic, cfg: PPOConfig, total_updates: int,
         B = flat["obs"].shape[0]
         mb = B // cfg.minibatches
         step0, n_steps = state.step, cfg.epochs * cfg.minibatches
+        batch_minor = (net.pixel and backend != "vmap"
+                       and learner_devices == 1
+                       and supports(flat["obs"].shape[1:], flat["obs"].dtype))
+        kernel = batch_minor and uses_kernel(backend)
 
         def epoch(carry, ek):
             perm = jax.random.permutation(ek, B)
@@ -159,8 +180,14 @@ def make_ppo_update(net: ActorCritic, cfg: PPOConfig, total_updates: int,
             def mb_step(carry, i):
                 state, kept = carry
                 idx = jax.lax.dynamic_slice_in_dim(perm, i * mb, mb)
-                batch = {k: v[idx] for k, v in flat.items()}
-                (loss, metrics), grads = grad_fn(state.params, batch)
+                batch = {k: v[idx] for k, v in flat.items()
+                         if not (batch_minor and k == "obs")}
+                if batch_minor:
+                    batch["obs"] = minibatch_obs(flat["obs"], idx,
+                                                 backend=backend)
+                (loss, metrics), grads = grad_fn(state.params, batch,
+                                                 batch_minor)
+                metrics = dict(metrics, obs_kernel_steps=jnp.int32(kernel))
                 lr = lr_fn(state.step)
                 params, opt_state = opt.update(grads, state.opt, state.params, lr)
                 new = PPOState(params, opt_state, state.step + 1)
@@ -183,6 +210,7 @@ def make_ppo_update(net: ActorCritic, cfg: PPOConfig, total_updates: int,
         (state, kept), (losses, metrics) = jax.lax.scan(
             epoch, (state, kept), keys)
         out = {k: jnp.mean(v) for k, v in metrics.items()}
+        out["obs_kernel_steps"] = jnp.sum(metrics["obs_kernel_steps"])
         out["loss"] = jnp.mean(losses)
         if keep_steps:
             out["steps"] = kept
@@ -377,7 +405,10 @@ def init_train_device(pool, cfg: PPOConfig, seed: int = 0,
     total_updates = max(
         1, cfg.total_steps // steps_per_iter
     ) * cfg.epochs * cfg.minibatches
-    opt, update = make_ppo_update(net, cfg, total_updates, keep_steps=True)
+    mesh = getattr(pool, "mesh", None)
+    opt, update = make_ppo_update(
+        net, cfg, total_updates, keep_steps=True,
+        learner_devices=1 if mesh is None else mesh.size)
     state = PPOState(params=params, opt=opt.init(params), step=jnp.int32(0))
 
     # policy placement (distributed/sharding.py): replicated across the
@@ -387,7 +418,6 @@ def init_train_device(pool, cfg: PPOConfig, seed: int = 0,
     # inherits it without explicit in_shardings — and takes back on the
     # next call the placement it returned: a leaf left unplaced would
     # come back placed and compile the program a second time.
-    mesh = getattr(pool, "mesh", None)
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec
 

@@ -15,11 +15,13 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.env_step.ops import env_multi_step, env_step
 from repro.kernels.image import ops as image
+from repro.kernels.minibatch_obs.ops import minibatch_obs
 
 KERNEL = "tpu_custom_call"
 
@@ -85,6 +87,11 @@ def _pong_render(s):
                                    backend="pallas")
 
 
+def _minibatch_obs(s):  # pong_ppo's update: T=128 x N=1024, 4 minibatches
+    return minibatch_obs.lower(s((131072, 4, 84, 84), jnp.uint8),
+                               s((32768,), jnp.int32), backend="pallas")
+
+
 def _decode(b, h, hkv, t, d, block_t):
     def lower(s):
         return decode_attention.lower(
@@ -101,6 +108,7 @@ CASES = {
     "resize": _resize,
     "crop": _crop,
     "pong_render": _pong_render,
+    "minibatch_obs": _minibatch_obs,
     # rl/policy_lm.py's default policy (H=4, Hkv=2, hd=16, T=max_len=64)
     # over 1024 lanes, and a serving cache (B=32, T=1024)
     "decode_attention_policy": _decode(1024, 4, 2, 64, 16, 64),
@@ -150,3 +158,43 @@ def test_image_chain_hands_uint8_between_kernels(one_chip):
         assert set(images_in) <= {"u8"}, (name, images_in)
     assert kernels["grayscale"][1] == ["u8"] * 3
     assert kernels["resize"][1] == ["u8"]
+
+
+def test_pong_update_makes_conv1_operand_in_one_kernel(one_chip, monkeypatch):
+    """PongClassic-v5's PPO update at the ``pong_ppo`` cell's T=128 and
+    N=1024 (4 epochs x 4 minibatches of 32768 stacks): each minibatch
+    step's conv1 operand comes from the ``minibatch_obs`` kernel, and the
+    loop body keeps neither XLA's convert-multiply of the gathered
+    stacks nor a copy of a 32768 x 28224-element operand."""
+    import repro
+    from repro.kernels import backend as kernel_backend
+    from repro.rl.nets import ActorCritic
+    from repro.rl.ppo import PPOConfig, PPOState, make_ppo_update
+
+    # the described chip is not the process's backend: take its branch
+    monkeypatch.setattr(kernel_backend, "default_backend", lambda: "pallas")
+    net = ActorCritic(repro.make("PongClassic-v5", num_envs=2, seed=0).spec)
+    opt, update = make_ppo_update(net, PPOConfig(num_steps=128), 16,
+                                  keep_steps=True)
+    state = jax.eval_shape(
+        lambda k: (lambda p: PPOState(p, opt.init(p), jnp.int32(0)))(
+            net.init(k)), jax.random.PRNGKey(0))
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = jax.tree.map(lambda x: s(x.shape, x.dtype), state)
+    t, n = 128, 1024
+    rollout = {"obs": s((t, n, 4, 84, 84), jnp.uint8),
+               "actions": s((t, n), jnp.int32)}
+    for k in ("logp", "values", "adv", "ret"):
+        rollout[k] = s((t, n), jnp.float32)
+    text = jax.jit(update).lower(state, rollout,
+                                 s((2,), jnp.uint32)).compile().as_text()
+    kernels = re.findall(r"%(\w+)\.\d+ = \S+ custom-call\(.*"
+                         + KERNEL, text)
+    assert kernels == ["minibatch_obs"], kernels
+    assert "convert_multiply" not in text
+    operand = 32768 * 4 * 84 * 84
+    for dims in re.findall(r"= \w+\[([\d,]+)\]\{[^}]*\} copy\(", text):
+        assert np.prod([int(d) for d in dims.split(",")]) != operand, dims
